@@ -16,6 +16,7 @@ import numpy as np
 
 import repro.configs as configs
 from repro.configs.base import TierScapeRunConfig
+from repro.launch import compile_cache
 from repro.models import Model
 from repro.serving import TieredEngine
 from repro.serving.kv_cache import COLD, HOST4, HOST8, WARM
@@ -46,6 +47,7 @@ def main() -> None:
                     help="submit unequal prompt lengths (per-slot decode)")
     args = ap.parse_args()
     prefetch = not args.no_prefetch and not args.serial_migration
+    compile_cache.enable()
 
     cfg = configs.get_smoke(args.arch)
     model = Model(cfg)

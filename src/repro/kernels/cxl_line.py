@@ -35,7 +35,7 @@ def _cxl_encode_kernel(page_ref, payload_ref, scale_ref, bits_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def cxl_encode_pages(pages: jax.Array, interpret: bool = True):
+def cxl_encode_pages(pages: jax.Array, *, interpret: bool):
     """pages [P, T, KV, hd] bf16 -> (payload int8, scales [P, T, KV] f32,
     line_bits [P, T, KV, hd // CXL_LINE_ELEMS] int32)."""
     p, t, kv, hd = pages.shape
@@ -64,7 +64,7 @@ def _cxl_decode_kernel(payload_ref, scale_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def cxl_decode_pages(payload: jax.Array, scales: jax.Array, interpret: bool = True):
+def cxl_decode_pages(payload: jax.Array, scales: jax.Array, *, interpret: bool):
     """(payload [P, T, KV, hd] int8, scales [P, T, KV]) -> pages f32."""
     p, t, kv, hd = payload.shape
     return pl.pallas_call(

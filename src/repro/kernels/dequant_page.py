@@ -27,7 +27,8 @@ def dequant_pages(
     scales: jax.Array,
     bits: int,
     out_dtype=jnp.bfloat16,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """payload [P, T, KV, hd(|//2)], scales [P, T, KV] -> pages [P, T, KV, hd]."""
     p, t, kv, hdp = payload.shape

@@ -8,12 +8,18 @@ pages appear as sentinel rows emitting a "would-have-touched" mass, and the
 logsumexp merge happens in VMEM scratch — exactly one Pallas launch per
 decode step, O(1) in tier count.
 
+``compiled()`` is the one platform switch: on a TPU every kernel compiles
+(a kernel the compiler refuses raises — there is no fallback to interpret
+mode or to the oracle) and the serving engine's decode step runs the fused
+kernel; on any other backend the kernels run in Pallas interpret mode and
+the engine's decode step runs the jnp oracle (tests and CPU tools only).
+
 ``use_fused(False)`` flips back to the legacy per-pool path (one kernel
 launch per tier pool + a dense recent pass + a post-hoc jnp merge) — kept
 as the equivalence oracle: outputs and normalized hotness must match the
 fused path to fp32 tolerance. ``use_pallas`` independently toggles kernel
-vs pure-jnp oracle (ref.py); kernels run in interpret mode on CPU (the TPU
-lowering is exercised by the dry-run).
+vs pure-jnp oracle (ref.py) for tests. The per-pool kernel is off the
+served path and is not built for the TPU lowering.
 
 ``page_hotness`` turns per-page mass telemetry into the normalized hotness
 the TierScape manager consumes. ``launch_count``/``reset_launch_count``
@@ -62,6 +68,12 @@ _LAUNCHES = 0
 # kept for back-compat and the equivalence tests; the ``decode_fused``
 # baseline guard pins this to 0.
 _COPY_BYTES = 0
+
+
+def compiled() -> bool:
+    """True on a TPU backend: kernels compile and the engine decodes with
+    the fused kernel. Elsewhere kernels run in interpret mode."""
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas(flag: bool) -> None:
@@ -114,14 +126,15 @@ def decode_launches_per_step(n_pools: int) -> int:
 
 def quant_pages(pages: Array, bits: int) -> Tuple[Array, Array]:
     if _USE_PALLAS:
-        out = quant_pages_kernel(pages, bits)
+        out = quant_pages_kernel(pages, bits, interpret=not compiled())
         return out[0], out[1]
     return _ref.quant_kv_page(pages, bits)
 
 
 def dequant_pages(payload: Array, scales: Array, bits: int, out_dtype=jnp.bfloat16) -> Array:
     if _USE_PALLAS:
-        return dequant_pages_kernel(payload, scales, bits, out_dtype)
+        return dequant_pages_kernel(payload, scales, bits, out_dtype,
+                                    interpret=not compiled())
     return _ref.dequant_kv_page(payload, scales, bits).astype(out_dtype)
 
 
@@ -133,24 +146,19 @@ def transcode_pages(
     if src_bits == dst_bits:
         return payload, scales
     if _USE_PALLAS:
-        out = transcode_pages_kernel(payload, scales, src_bits, dst_bits)
+        out = transcode_pages_kernel(payload, scales, src_bits, dst_bits,
+                                     interpret=not compiled())
         return out[0], out[1]
     return _ref.transcode_kv_page(payload, scales, src_bits, dst_bits)
 
 
 def _pool_partials(q: Array, pool: Dict[str, Array]):
-    fn = paged_attn_kernel if _USE_PALLAS else _ref.paged_quant_attention
     _count_launch()
-    return fn(
-        q,
-        pool["k_pages"],
-        pool["k_scales"],
-        pool["v_pages"],
-        pool["v_scales"],
-        pool["page_table"],
-        pool["n_pages"],
-        pool["bits"],
-    )
+    args = (q, pool["k_pages"], pool["k_scales"], pool["v_pages"],
+            pool["v_scales"], pool["page_table"], pool["n_pages"], pool["bits"])
+    if _USE_PALLAS:
+        return paged_attn_kernel(*args, interpret=not compiled())
+    return _ref.paged_quant_attention(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -340,6 +348,7 @@ def _fused_path(q, pools, recent_k, recent_v, recent_len, host, with_telemetry):
         out, m, l, mass, base = fused_attn_kernel(
             q, k8, s8k, v8, s8v, k4, s4k, v4, s4v, summary,
             recent_k, recent_v, uni_slot, uni_tier, rlen, page_tokens=t,
+            interpret=not compiled(),
         )
         if not with_telemetry:
             return out

@@ -42,9 +42,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.packing import unpack_int4 as _unpack_int4
 
-# jax.sharding-style API drift: CompilerParams was TPUCompilerParams in 0.4.x.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 # Tier codes carried by the unified page table (``fused_tiered_attention``).
@@ -149,7 +146,8 @@ def paged_quant_attention(
     page_table: jax.Array,  # [B, MP] int32
     n_pages: jax.Array,  # [B] int32
     bits: int,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Flash partials over one pool: (out_unnorm, m, l, page_mass)."""
     b, h, hd = q.shape
@@ -190,7 +188,7 @@ def paged_quant_attention(
             jax.ShapeDtypeStruct((b, mp), jnp.float32),
             jax.ShapeDtypeStruct((b, mp), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -209,7 +207,7 @@ def _fused_attn_kernel(
     tier_ref,  # [B, MS] int32 TIER_* code per unified slot
     rlen_ref,  # [B] int32 dense recent-window fill
     # array operands (blocked)
-    q_ref,  # [1, H, hd]
+    q_ref,  # [1, G, KV, hd] (head kv*G + g at [g, kv])
     k8_ref,  # [1, T, KV, hd] int8 group buffer
     s8k_ref,  # [1, T, KV]
     v8_ref,
@@ -222,27 +220,31 @@ def _fused_attn_kernel(
     rk_ref,  # [1, R, KV, hd] dense recent window
     rv_ref,
     # outputs
-    out_ref,  # [1, H, hd] f32 (NORMALIZED — merge happens in-kernel)
-    m_ref,  # [1, H] f32 merged running max
-    l_ref,  # [1, H] f32 merged partition mass
-    mass_ref,  # [1, 1] f32 per (b, slot): softmax mass at its local base
-    base_ref,  # [1, 1] f32 per (b, slot): the local base
+    out_ref,  # [1, G, KV, hd] f32 (NORMALIZED — merge happens in-kernel)
+    m_ref,  # [1, G, KV, hd] f32 merged running max (lane-replicated)
+    l_ref,  # [1, G, KV, hd] f32 merged partition mass (lane-replicated)
+    mass_ref,  # [1, 1, 1, 1] f32 per (b, slot): softmax mass at its local base
+    base_ref,  # [1, 1, 1, 1] f32 per (b, slot): the local base
     # scratch
-    acc_ref,  # [KV, G, hd] f32
-    run_m_ref,  # [KV, G] f32
-    run_l_ref,  # [KV, G] f32
+    acc_ref,  # [G, KV, hd] f32
+    run_m_ref,  # [G, KV, hd] f32 (lane-replicated)
+    run_l_ref,  # [G, KV, hd] f32 (lane-replicated)
     *,
-    kv: int,
     group: int,
     page_tokens: int,
     ms: int,
 ):
     """One grid step = one unified-table slot; the final step (p == ms) is
     the dense recent window + in-VMEM finalization. Pool rows accumulate
-    (acc, m, l) online exactly like the per-pool kernel; host sentinel rows
-    touch no payload — they score the page's key centroid against q and
-    emit ``page_tokens * sum(exp(s - max s))`` as the would-have-touched
-    mass (telemetry only, never accumulated)."""
+    (acc, m, l) online; host sentinel rows touch no payload — they score the
+    page's key centroid against q and emit
+    ``page_tokens * sum(exp(s - max s))`` as the would-have-touched mass
+    (telemetry only, never accumulated).
+
+    Every value keeps the KV heads on sublanes and head_dim on lanes: a
+    score is a lane reduction of ``k * q`` kept as a trailing unit dim, and
+    the token axis is the leading (vreg-stacking) dim, so the body needs no
+    transpose, relayout or batched matmul. GQA groups unroll statically."""
     b = pl.program_id(0)
     p = pl.program_id(1)
     hd = acc_ref.shape[-1]
@@ -253,23 +255,31 @@ def _fused_attn_kernel(
         run_m_ref[...] = jnp.full_like(run_m_ref, NEG_INF)
         run_l_ref[...] = jnp.zeros_like(run_l_ref)
 
-    q = q_ref[0].astype(jnp.float32).reshape(kv, group, hd) / (hd**0.5)
+    q = q_ref[0].astype(jnp.float32) / (hd**0.5)  # [G, KV, hd]
     tid = tier_ref[b, jnp.minimum(p, ms - 1)]
+
+    def _scores(k):
+        # [T, KV, hd] keys -> per-group scores [T, KV, 1].
+        return [jnp.sum(k * q[g][None], axis=-1, keepdims=True) for g in range(group)]
+
+    def _emit(value, base):
+        mass_ref[0] = value.reshape(1, 1, 1)
+        base_ref[0] = base.reshape(1, 1, 1)
 
     def _accumulate(k, v):
         # Online-softmax update over one full page ([T, KV, hd] f32 k/v).
-        scores = jnp.einsum("kgh,tkh->kgt", q, k)  # [KV, G, T]
-        page_max = jnp.max(scores, axis=-1)
-        m_old = run_m_ref[...]
-        m_new = jnp.maximum(m_old, page_max)
-        alpha = jnp.exp(m_old - m_new)
-        e = jnp.exp(scores - m_new[..., None])
-        run_l_ref[...] = run_l_ref[...] * alpha + jnp.sum(e, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + jnp.einsum("kgt,tkh->kgh", e, v)
-        run_m_ref[...] = m_new
-        pbase = jnp.max(page_max)
-        mass_ref[0, 0] = jnp.sum(jnp.exp(scores - pbase))
-        base_ref[0, 0] = pbase
+        scores = _scores(k)
+        for g, s in enumerate(scores):
+            page_max = jnp.max(s, axis=0)  # [KV, 1]
+            m_old = run_m_ref[g]
+            m_new = jnp.maximum(m_old, page_max)
+            alpha = jnp.exp(m_old - m_new)
+            e = jnp.exp(s - m_new[None])  # [T, KV, 1]
+            run_l_ref[g] = run_l_ref[g] * alpha + jnp.sum(e, axis=0)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.sum(e * v, axis=0)
+            run_m_ref[g] = m_new
+        pbase = _max_all(scores)
+        _emit(sum(_total(jnp.exp(s - pbase), jnp.sum) for s in scores), pbase)
 
     @pl.when((p < ms) & (tid == TIER_INT8))
     def _pool8():
@@ -279,45 +289,56 @@ def _fused_attn_kernel(
 
     @pl.when((p < ms) & (tid == TIER_INT4))
     def _pool4():
-        k = _unpack_int4(k4_ref[0].astype(jnp.int32)) * s4k_ref[0][..., None]
-        v = _unpack_int4(v4_ref[0].astype(jnp.int32)) * s4v_ref[0][..., None]
+        k = _unpack_int4(k4_ref[0]) * s4k_ref[0][..., None]
+        v = _unpack_int4(v4_ref[0]) * s4v_ref[0][..., None]
         _accumulate(k, v)
 
     @pl.when((p < ms) & (tid == TIER_HOST))
     def _host_sentinel():
         kbar = sum_ref[0].astype(jnp.float32)  # [KV, hd]
-        s = jnp.einsum("kgh,kh->kg", q, kbar)  # [KV, G]
-        pbase = jnp.max(s)
-        mass_ref[0, 0] = page_tokens * jnp.sum(jnp.exp(s - pbase))
-        base_ref[0, 0] = pbase
+        scores = [jnp.sum(q[g] * kbar, axis=-1, keepdims=True) for g in range(group)]
+        pbase = _max_all(scores)
+        _emit(page_tokens * sum(_total(jnp.exp(s - pbase), jnp.sum) for s in scores), pbase)
 
     @pl.when((p < ms) & (tid < 0))
     def _skip():
-        mass_ref[0, 0] = 0.0
-        base_ref[0, 0] = NEG_INF
+        _emit(jnp.zeros((1, 1), jnp.float32), jnp.full((1, 1), NEG_INF, jnp.float32))
 
     @pl.when(p == ms)
     def _recent_and_finalize():
         rk = rk_ref[0].astype(jnp.float32)  # [R, KV, hd]
         rv = rv_ref[0].astype(jnp.float32)
-        r = rk.shape[0]
-        scores = jnp.einsum("kgh,rkh->kgr", q, rk)  # [KV, G, R]
-        valid = jax.lax.broadcasted_iota(jnp.int32, (1, 1, r), 2) < rlen_ref[b]
-        scores = jnp.where(valid, scores, NEG_INF)
-        page_max = jnp.max(scores, axis=-1)
-        m_old = run_m_ref[...]
-        m_new = jnp.maximum(m_old, page_max)
-        # Safe shift: both the recent window (rlen may be 0) and the pools
-        # (all-host / empty) can be vacuous, so NEG_INF never enters exp.
-        shift = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-        e = jnp.where(valid, jnp.exp(scores - shift[..., None]), 0.0)
-        alpha = jnp.where(m_old > NEG_INF / 2, jnp.exp(m_old - shift), 0.0)
-        l_new = run_l_ref[...] * alpha + jnp.sum(e, axis=-1)
-        acc = acc_ref[...] * alpha[..., None] + jnp.einsum("kgt,tkh->kgh", e, rv)
-        out_ref[0] = (acc / jnp.maximum(l_new, 1e-30)[..., None]).reshape(kv * group, hd)
-        m_fin = jnp.where(l_new > 0.0, m_new, 0.0)
-        m_ref[0] = m_fin.reshape(kv * group)
-        l_ref[0] = l_new.reshape(kv * group)
+        valid = jax.lax.broadcasted_iota(jnp.int32, (rk.shape[0], 1, 1), 0) < rlen_ref[b]
+        for g, s in enumerate(_scores(rk)):
+            s = jnp.where(valid, s, NEG_INF)
+            m_old = run_m_ref[g]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=0))
+            # Safe shift: both the recent window (rlen may be 0) and the pools
+            # (all-host / empty) can be vacuous, so NEG_INF never enters exp.
+            shift = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+            e = jnp.where(valid, jnp.exp(s - shift[None]), 0.0)
+            alpha = jnp.where(m_old > NEG_INF / 2, jnp.exp(m_old - shift), 0.0)
+            l_new = run_l_ref[g] * alpha + jnp.sum(e, axis=0)
+            acc = acc_ref[g] * alpha + jnp.sum(e * rv, axis=0)
+            out_ref[0, g] = acc / jnp.maximum(l_new, 1e-30)
+            m_ref[0, g] = jnp.where(l_new > 0.0, m_new, 0.0)
+            l_ref[0, g] = l_new
+
+
+def _total(x, op):
+    """Reduce a [..., KV, 1] value to [1, 1] with ``op`` (jnp.max/jnp.sum),
+    one leading axis at a time (a reshape would need a lane broadcast)."""
+    while x.ndim > 2:
+        x = op(x, axis=0)
+    return op(x, axis=0, keepdims=True)
+
+
+def _max_all(scores):
+    """Max over every group's [..., KV, 1] scores, as a [1, 1] value."""
+    out = _total(scores[0], jnp.max)
+    for s in scores[1:]:
+        out = jnp.maximum(out, _total(s, jnp.max))
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("page_tokens", "interpret"))
@@ -337,8 +358,9 @@ def fused_tiered_attention(
     uni_slot: jax.Array,  # [B, MS] int32
     uni_tier: jax.Array,  # [B, MS] int32 TIER_* codes
     recent_len: jax.Array,  # [B] int32
+    *,
     page_tokens: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Single launch over every tier + host sentinels + the recent window.
 
@@ -347,6 +369,10 @@ def fused_tiered_attention(
     logsumexp stats (for hotness normalization) and mass/base follow the
     unified-table slot layout (pool pages: exact page mass at its local
     base; host sentinels: would-have-touched mass; invalid: 0 / NEG_INF).
+
+    Heads enter the kernel group-major ([B, G, KV, hd]) and the per-head and
+    per-slot outputs carry trailing unit dims, so every block's last two
+    dims equal the array's — the shape rule of the TPU lowering.
     """
     b, h, hd = q.shape
     t = k8.shape[1]
@@ -355,6 +381,7 @@ def fused_tiered_attention(
     r = recent_k.shape[1]
     group = h // kv
     hd4 = k4.shape[-1]
+    qg = q.reshape(b, kv, group, hd).transpose(0, 2, 1, 3)
 
     def _gated(code, ndim):
         # Fetch the row the table names only when this row's tier matches;
@@ -366,11 +393,17 @@ def fused_tiered_attention(
 
         return index_map
 
+    def _per_seq(ndim):
+        return lambda bi, pi, *_: (bi,) + (0,) * (ndim - 1)
+
+    def _per_slot(bi, pi, *_):
+        return (bi, jnp.minimum(pi, ms - 1), 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, ms + 1),
         in_specs=[
-            pl.BlockSpec((1, h, hd), lambda bi, pi, *_: (bi, 0, 0)),
+            pl.BlockSpec((1, group, kv, hd), _per_seq(4)),
             pl.BlockSpec((1, t, kv, hd), _gated(TIER_INT8, 4)),
             pl.BlockSpec((1, t, kv), _gated(TIER_INT8, 3)),
             pl.BlockSpec((1, t, kv, hd), _gated(TIER_INT8, 4)),
@@ -380,38 +413,42 @@ def fused_tiered_attention(
             pl.BlockSpec((1, t, kv, hd4), _gated(TIER_INT4, 4)),
             pl.BlockSpec((1, t, kv), _gated(TIER_INT4, 3)),
             pl.BlockSpec((1, kv, hd), _gated(TIER_HOST, 3)),
-            pl.BlockSpec((1, r, kv, hd), lambda bi, pi, *_: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, r, kv, hd), lambda bi, pi, *_: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, r, kv, hd), _per_seq(4)),
+            pl.BlockSpec((1, r, kv, hd), _per_seq(4)),
         ],
         out_specs=[
-            pl.BlockSpec((1, h, hd), lambda bi, pi, *_: (bi, 0, 0)),
-            pl.BlockSpec((1, h), lambda bi, pi, *_: (bi, 0)),
-            pl.BlockSpec((1, h), lambda bi, pi, *_: (bi, 0)),
-            pl.BlockSpec((1, 1), lambda bi, pi, *_: (bi, jnp.minimum(pi, ms - 1))),
-            pl.BlockSpec((1, 1), lambda bi, pi, *_: (bi, jnp.minimum(pi, ms - 1))),
+            pl.BlockSpec((1, group, kv, hd), _per_seq(4)),
+            pl.BlockSpec((1, group, kv, hd), _per_seq(4)),
+            pl.BlockSpec((1, group, kv, hd), _per_seq(4)),
+            pl.BlockSpec((1, 1, 1, 1), _per_slot),
+            pl.BlockSpec((1, 1, 1, 1), _per_slot),
         ],
         scratch_shapes=[
-            pltpu.VMEM((kv, group, hd), jnp.float32),
-            pltpu.VMEM((kv, group), jnp.float32),
-            pltpu.VMEM((kv, group), jnp.float32),
+            pltpu.VMEM((group, kv, hd), jnp.float32),
+            pltpu.VMEM((group, kv, hd), jnp.float32),
+            pltpu.VMEM((group, kv, hd), jnp.float32),
         ],
     )
     out, m, l, mass, base = pl.pallas_call(
-        functools.partial(
-            _fused_attn_kernel, kv=kv, group=group, page_tokens=page_tokens, ms=ms
-        ),
+        functools.partial(_fused_attn_kernel, group=group, page_tokens=page_tokens, ms=ms),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, ms), jnp.float32),
-            jax.ShapeDtypeStruct((b, ms), jnp.float32),
+            jax.ShapeDtypeStruct((b, group, kv, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, group, kv, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, group, kv, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, ms, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, ms, 1, 1), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(uni_slot, uni_tier, recent_len, q, k8, s8k, v8, s8v, k4, s4k, v4, s4v,
+        name="fused_tiered_attention",
+    )(uni_slot, uni_tier, recent_len, qg, k8, s8k, v8, s8v, k4, s4k, v4, s4v,
       host_summary, recent_k, recent_v)
-    return out, m, l, mass, base
+
+    def heads(x):  # [B, G, KV, ...] -> [B, H, ...]
+        return x.transpose(0, 2, 1, 3).reshape(b, h, -1)
+
+    return (heads(out), heads(m)[..., 0], heads(l)[..., 0],
+            mass.reshape(b, ms), base.reshape(b, ms))

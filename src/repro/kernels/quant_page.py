@@ -31,7 +31,7 @@ def _quant_kernel(page_ref, payload_ref, scale_ref, *, bits: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def quant_pages(pages: jax.Array, bits: int, interpret: bool = True):
+def quant_pages(pages: jax.Array, bits: int, *, interpret: bool):
     """pages [P, T, KV, hd] bf16 -> (payload, scales [P, T, KV])."""
     p, t, kv, hd = pages.shape
     hd_out = hd if bits == 8 else hd // 2

@@ -1,13 +1,16 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These define the exact semantics the kernels must reproduce (tests sweep
-shapes/dtypes and assert_allclose kernel-vs-ref). They are also the portable
-fallback path used when Pallas is unavailable.
+shapes/dtypes and assert_allclose kernel-vs-ref; ``chip_smoke.py`` compares
+the compiled kernels against them on the chip). Off the TPU the serving
+engine's decode step runs these oracles (see ``ops.compiled``); on the TPU
+it never does.
 
 KV-page quantization layout (serving hot path):
   page:    [T, KV, hd]  bf16 source (T tokens per page)
   int8:    payload [T, KV, hd] int8, scales [T, KV] f32 (absmax over hd)
-  int4:    payload [T, KV, hd//2] uint8 (lo nibble = even idx), scales as int8
+  int4:    payload [T, KV, hd//2] uint8 (byte i = element i in the lo nibble,
+           element i + hd/2 in the hi nibble; see kernels.packing), scales as int8
 
 Paged attention partials follow flash-decoding: each tier's pool produces
 (out_unnorm, m, l, page_mass); partials merge exactly via logsumexp. The

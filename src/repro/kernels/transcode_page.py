@@ -10,8 +10,8 @@ migrations.
 
 Grid over pages; each program transcodes one [T, KV, hd] page. The dequant
 multiply, absmax reduce and requant divide all vectorize on the VPU with hd
-on the 128-lane axis. int4 payloads pack adjacent hd pairs into one uint8
-(lo nibble = even index), matching quant_page/dequant_page.
+on the 128-lane axis. int4 payloads use the ``kernels.packing`` layout
+(element i and i + hd/2 share a byte), matching quant_page/dequant_page.
 """
 
 from __future__ import annotations
@@ -49,7 +49,8 @@ def transcode_pages(
     scales: jax.Array,
     src_bits: int,
     dst_bits: int,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """payload [P, T, KV, hd(|//2)], scales [P, T, KV] ->
     (payload' [P, T, KV, hd'(|//2)], scales' [P, T, KV]) at dst_bits."""

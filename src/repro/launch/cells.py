@@ -183,8 +183,9 @@ def build_cell(
         ts_cfg = TierScapeRunConfig(enabled=True)
         la = _attn_layer_count(cfg)
         n_pages = shape.seq_len // page_tokens
-        warm_pages = max(int(n_pages * warm_frac) * max(bsz, 1), 8)
-        cold_pages = max(n_pages * max(bsz, 1), 8)
+        # Class-buffer rows are global across attention layers.
+        warm_pages = max(int(n_pages * warm_frac) * max(bsz, 1) * la, 8)
+        cold_pages = max(n_pages * max(bsz, 1) * la, 8)
         tkv = jax.eval_shape(
             lambda: serve_rt.init_tiered_kv_state(
                 cfg,

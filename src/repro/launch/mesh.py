@@ -6,11 +6,6 @@ second data-parallel axis crossing the inter-pod (DCN) boundary.
 
 A FUNCTION, not a module constant: importing this module must never touch
 jax device state (the dry-run pins the fake-device count before any init).
-
-The helpers below paper over the jax.sharding API drift around meshes:
-newer jax exposes ``AxisType`` / ``make_mesh(..., axis_types=)`` and
-``AbstractMesh(shape, names)``; 0.4.x has neither. All call sites in this
-repo go through these helpers so the rest of the code is version-agnostic.
 """
 
 from __future__ import annotations
@@ -18,31 +13,21 @@ from __future__ import annotations
 import jax
 import jax.sharding as jsh
 
-_HAS_AXIS_TYPES = hasattr(jsh, "AxisType")
 
-
-def _mk(shape, axes):
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes), axis_types=(jsh.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, devices=None):
+    """Mesh over ``devices`` (default: the first prod(shape) devices)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(jsh.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh helper for tests/examples (1-device CPU friendly)."""
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_abstract_mesh(shape, axes):
     """Device-free mesh (spec logic only needs axis sizes, not devices)."""
-    try:  # newer jax: AbstractMesh(shape, axis_names)
-        return jsh.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:  # 0.4.x: AbstractMesh(((name, size), ...))
-        return jsh.AbstractMesh(tuple(zip(axes, shape)))
+    return jsh.AbstractMesh(tuple(shape), tuple(axes))

@@ -90,7 +90,10 @@ class TieredKVState:
 
     Payload storage is CODEC-CLASS-MAJOR: one shared int8-class buffer
     (``c8_*``) and one int4-class buffer (``c4_*``), each holding the rows
-    of EVERY tier pool of that codec width. Per-pool page tables
+    of EVERY tier pool of that codec width and of every layer (a row holds
+    one layer's page; rows are allocated across layers, so the buffers
+    carry no layer axis and every layer's kernel addresses the whole
+    buffer without a per-layer slice). Per-pool page tables
     (``warm_table``/``cold_table``) stay, but their entries are GLOBAL rows
     of the pool's class buffer (``SlotAllocator`` row ranges carve the
     buffer up per pool) — so N same-class tiers address one buffer with
@@ -107,11 +110,11 @@ class TieredKVState:
     payload.
     """
 
-    c8_k: jax.Array  # [L, P8, T, KV, hd] int8 — shared int8-class rows
-    c8_k_scales: jax.Array  # [L, P8, T, KV] f32
+    c8_k: jax.Array  # [P8, T, KV, hd] int8 — shared int8-class rows
+    c8_k_scales: jax.Array  # [P8, T, KV] f32
     c8_v: jax.Array
     c8_v_scales: jax.Array
-    c4_k: jax.Array  # [L, P4, T, KV, hd//2] uint8 — shared int4-class rows
+    c4_k: jax.Array  # [P4, T, KV, hd//2] uint8 — shared int4-class rows
     c4_k_scales: jax.Array
     c4_v: jax.Array
     c4_v_scales: jax.Array
@@ -128,8 +131,13 @@ class TieredKVState:
     host_n: jax.Array  # [L, B] int32
 
 
-# Class-buffer payload fields by codec width; ``class_field("c8", "k")`` etc.
+# Class-buffer payload fields (``c8_k``, ``c4_v_scales``, ...) and the
+# fields that carry a leading attention-layer axis.
 CLASS_FIELDS = ("k", "k_scales", "v", "v_scales")
+PER_LAYER_FIELDS = (
+    "warm_table", "warm_n", "cold_table", "cold_n", "recent_k", "recent_v",
+    "host_summary", "host_table", "host_n",
+)
 
 
 def class_rows_of(
@@ -167,14 +175,14 @@ def init_tiered_kv_state(
     rows = class_rows_of(warm_pages, cold_pages, warm_bits, cold_bits)
     p8, p4 = rows[8], rows[4]
     return TieredKVState(
-        c8_k=jnp.zeros((la, p8, t, kv, hd), jnp.int8),
-        c8_k_scales=jnp.ones((la, p8, t, kv), jnp.float32),
-        c8_v=jnp.zeros((la, p8, t, kv, hd), jnp.int8),
-        c8_v_scales=jnp.ones((la, p8, t, kv), jnp.float32),
-        c4_k=jnp.zeros((la, p4, t, kv, hd // 2), jnp.uint8),
-        c4_k_scales=jnp.ones((la, p4, t, kv), jnp.float32),
-        c4_v=jnp.zeros((la, p4, t, kv, hd // 2), jnp.uint8),
-        c4_v_scales=jnp.ones((la, p4, t, kv), jnp.float32),
+        c8_k=jnp.zeros((p8, t, kv, hd), jnp.int8),
+        c8_k_scales=jnp.ones((p8, t, kv), jnp.float32),
+        c8_v=jnp.zeros((p8, t, kv, hd), jnp.int8),
+        c8_v_scales=jnp.ones((p8, t, kv), jnp.float32),
+        c4_k=jnp.zeros((p4, t, kv, hd // 2), jnp.uint8),
+        c4_k_scales=jnp.ones((p4, t, kv), jnp.float32),
+        c4_v=jnp.zeros((p4, t, kv, hd // 2), jnp.uint8),
+        c4_v_scales=jnp.ones((p4, t, kv), jnp.float32),
         warm_table=jnp.zeros((la, batch, max_pages_per_seq), jnp.int32),
         warm_n=jnp.zeros((la, batch), jnp.int32),
         cold_table=jnp.zeros((la, batch, max_pages_per_seq), jnp.int32),
@@ -201,8 +209,6 @@ def make_sp_pool_attention(mesh: Mesh, batch_axes: Tuple[str, ...]):
     traffic all divide by the full mesh — the SPMD-auto path instead
     all-gathers the entire dequantized pool (the baseline bottleneck).
     """
-    from jax.experimental.shard_map import shard_map
-
     page_axes: Tuple[str, ...] = tuple(batch_axes) + ("model",)
     page_spec = page_axes if len(page_axes) > 1 else page_axes[0]
     bax = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
@@ -229,7 +235,7 @@ def make_sp_pool_attention(mesh: Mesh, batch_axes: Tuple[str, ...]):
         mp = pool["page_table"].shape[1]
         b = pool["page_table"].shape[0]
         slot_pos = jnp.broadcast_to(jnp.arange(mp, dtype=jnp.int32)[None], (b, mp))
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda *a: partial_fn(*a, bits=bits),
             mesh=mesh,
             in_specs=(
@@ -249,7 +255,7 @@ def make_sp_pool_attention(mesh: Mesh, batch_axes: Tuple[str, ...]):
                 P(bax, "model"),  # local masses stay slot-sharded
                 P(bax, "model"),
             ),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(q, pool["k_pages"], pool["k_scales"], pool["v_pages"],
                   pool["v_scales"], pool["page_table"], slot_pos, pool["n_pages"])
@@ -379,6 +385,14 @@ def make_tiered_decode_step(
             y = y + blk["attn"]["bo"]
         return x + y, recent_k, recent_v, hot
 
+    def layer_view(tkv: TieredKVState, li: int) -> Dict[str, jax.Array]:
+        """One attention layer's slice of the state. The class buffers are
+        not sliced: their rows are global across layers."""
+        view = {f: getattr(tkv, f)[li] for f in PER_LAYER_FIELDS}
+        view.update({f"{c}_{f}": getattr(tkv, f"{c}_{f}")
+                     for c in ("c8", "c4") for f in CLASS_FIELDS})
+        return view
+
     def step(params, token, tkv: TieredKVState, ssm_state):
         x = params["embed"][token]
         recent_len = tkv.recent_len
@@ -400,16 +414,7 @@ def make_tiered_decode_step(
 
             done = 0
             for g in range(n_apps):
-                layer_tkv = {
-                    f: getattr(tkv, f)[g]
-                    for f in (
-                        "c8_k", "c8_k_scales", "c8_v", "c8_v_scales",
-                        "c4_k", "c4_k_scales", "c4_v", "c4_v_scales",
-                        "warm_table", "warm_n", "cold_table", "cold_n",
-                        "recent_k", "recent_v",
-                        "host_summary", "host_table", "host_n",
-                    )
-                }
+                layer_tkv = layer_view(tkv, g)
                 x, rk, rv, hot = attend_tiered(params["shared"], x, layer_tkv, total_len, recent_len)
                 hn = layers.apply_norm(cfg.norm, params["shared"]["norm2"], x, cfg.norm_eps)
                 x = x + mlp_mod.mlp(params["shared"]["ffn"], cfg, hn)
@@ -432,16 +437,7 @@ def make_tiered_decode_step(
             n_layers = tkv.recent_k.shape[0]
             for li in range(n_layers):
                 blk = jax.tree.map(lambda a: a[li], params["blocks"])
-                layer_tkv = {
-                    f: getattr(tkv, f)[li]
-                    for f in (
-                        "c8_k", "c8_k_scales", "c8_v", "c8_v_scales",
-                        "c4_k", "c4_k_scales", "c4_v", "c4_v_scales",
-                        "warm_table", "warm_n", "cold_table", "cold_n",
-                        "recent_k", "recent_v",
-                        "host_summary", "host_table", "host_n",
-                    )
-                }
+                layer_tkv = layer_view(tkv, li)
                 x, rk, rv, hot = attend_tiered(blk, x, layer_tkv, total_len, recent_len)
                 hn = layers.apply_norm(cfg.norm, blk["norm2"], x, cfg.norm_eps)
                 if cfg.family == "moe":
@@ -491,14 +487,14 @@ def tiered_kv_state_specs(
     # Table slots shard with the pages (sequence parallelism).
     table_ax = "model" if sp_on else None
     return TieredKVState(
-        c8_k=P(None, page_ax, None, None, None),
-        c8_k_scales=P(None, page_ax, None, None),
-        c8_v=P(None, page_ax, None, None, None),
-        c8_v_scales=P(None, page_ax, None, None),
-        c4_k=P(None, page_ax, None, None, None),
-        c4_k_scales=P(None, page_ax, None, None),
-        c4_v=P(None, page_ax, None, None, None),
-        c4_v_scales=P(None, page_ax, None, None),
+        c8_k=P(page_ax, None, None, None),
+        c8_k_scales=P(page_ax, None, None),
+        c8_v=P(page_ax, None, None, None),
+        c8_v_scales=P(page_ax, None, None),
+        c4_k=P(page_ax, None, None, None),
+        c4_k_scales=P(page_ax, None, None),
+        c4_v=P(page_ax, None, None, None),
+        c4_v_scales=P(page_ax, None, None),
         warm_table=P(None, bax, table_ax),
         warm_n=P(None, bax),
         cold_table=P(None, bax, table_ax),

@@ -4,13 +4,17 @@ Request lifecycle: queue -> slot assignment -> prefill (dense, then pages
 compress into the warm tier) -> decode steps (tiered attention, telemetry)
 -> window boundary (TierScape placement) -> completion frees pages.
 
-This engine runs smoke-scale archs end-to-end on CPU (tests, examples,
-fig-benchmarks); the dry-run lowers its step function at full scale.
+One engine is one replica on one device: its parameters, its tiered KV
+state and its jitted decode step live on the device it is given, and every
+public method runs with that device as JAX's default, so nothing it creates
+lands anywhere else. On a TPU the decode step runs the compiled fused
+attention kernel; elsewhere it runs the jnp oracle (``kernels.ops.compiled``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -20,6 +24,8 @@ import numpy as np
 
 from repro.configs.base import ParallelConfig, TierScapeRunConfig
 from repro.core.manager import ManagerConfig
+from repro.kernels import ops as kops
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model, _attn_layer_count
 from repro.runtime import serve as serve_rt
 from repro.serving.kv_cache import (
@@ -83,8 +89,23 @@ class EngineStats:
     tco_savings_by_tenant: Dict[int, float] = dataclasses.field(default_factory=dict)
 
 
+def _on_device(method):
+    """Run an engine method with the engine's device as JAX's default, so
+    every array it creates (uploads, eager ops, kernel calls) lands there."""
+
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        with jax.default_device(self.device):
+            return method(self, *args, **kwargs)
+
+    return wrapped
+
+
 class TieredEngine:
-    """Single-host engine for attention/hybrid archs with tiered KV."""
+    """One replica on one device, for attention/hybrid archs with tiered KV.
+
+    ``device`` is the device the replica runs on (the first device when not
+    given); ``params`` are placed there."""
 
     def __init__(
         self,
@@ -95,12 +116,14 @@ class TieredEngine:
         max_seq_len: int = 512,
         recent_window: int = 32,
         ts: Optional[TierScapeRunConfig] = None,
-        mesh=None,
+        device: Optional[jax.Device] = None,
     ):
         cfg = model.cfg
         assert cfg.has_attention, "tiered KV serving needs attention layers"
+        self.device = device if device is not None else jax.devices()[0]
+        self.mesh = make_mesh((1, 1), ("data", "model"), devices=[self.device])
         self.model = model
-        self.params = params
+        self.params = jax.device_put(params, self.device)
         self.cfg = cfg
         self.bs = batch_slots
         self.pt = page_tokens
@@ -127,47 +150,48 @@ class TieredEngine:
             fault_plan = default_plan(
                 getattr(ts, "host_media_device", "") or "host_dram_pcie"
             )
-        self.cache = TieredKVCache(
-            cfg,
-            self.la,
-            batch_slots,
-            page_tokens,
-            max_seq_len,
-            recent_window,
-            mgr_cfg,
-            async_migration=ts.async_migration,
-            ring_slots=ts.media_ring_slots,
-            prefetch=ts.prefetch,
-            prefetch_max_pages=ts.prefetch_max_pages,
-            pool_bits={
-                "warm": getattr(ts, "warm_bits", 8),
-                "cold": getattr(ts, "cold_bits", 4),
-            },
-            host_media_device=getattr(ts, "host_media_device", ""),
-            fault_plan=fault_plan,
-        )
-        from repro.launch.mesh import make_mesh
-
-        default_mesh = mesh or make_mesh((1, 1), ("data", "model"))
+        with jax.default_device(self.device):
+            self.cache = TieredKVCache(
+                cfg,
+                self.la,
+                batch_slots,
+                page_tokens,
+                max_seq_len,
+                recent_window,
+                mgr_cfg,
+                async_migration=ts.async_migration,
+                ring_slots=ts.media_ring_slots,
+                prefetch=ts.prefetch,
+                prefetch_max_pages=ts.prefetch_max_pages,
+                pool_bits={
+                    "warm": getattr(ts, "warm_bits", 8),
+                    "cold": getattr(ts, "cold_bits", 4),
+                },
+                host_media_device=getattr(ts, "host_media_device", ""),
+                fault_plan=fault_plan,
+            )
+            # SSM side-state for hybrid archs.
+            if cfg.family == "hybrid":
+                s = cfg.ssm
+                di = s.d_inner(cfg.d_model)
+                cconv = di + 2 * s.n_groups * s.d_state
+                self.ssm_state = (
+                    jnp.zeros((cfg.n_layers, batch_slots, s.conv_kernel - 1, cconv),
+                              jnp.bfloat16),
+                    jnp.zeros(
+                        (cfg.n_layers, batch_slots, s.n_heads(cfg.d_model), s.head_dim,
+                         s.d_state),
+                        jnp.float32,
+                    ),
+                )
+            else:
+                self.ssm_state = (jnp.zeros((0,)), jnp.zeros((0,)))
+        self._prefill_fn = jax.jit(model.prefill)
         self._step_fn = jax.jit(
             serve_rt.make_tiered_decode_step(
-                model, default_mesh, ParallelConfig(), ts, use_kernels=False
+                model, self.mesh, ParallelConfig(), ts, use_kernels=kops.compiled()
             )
         )
-        # SSM side-state for hybrid archs.
-        if cfg.family == "hybrid":
-            s = cfg.ssm
-            di = s.d_inner(cfg.d_model)
-            cconv = di + 2 * s.n_groups * s.d_state
-            self.ssm_state = (
-                jnp.zeros((cfg.n_layers, batch_slots, s.conv_kernel - 1, cconv), jnp.bfloat16),
-                jnp.zeros(
-                    (cfg.n_layers, batch_slots, s.n_heads(cfg.d_model), s.head_dim, s.d_state),
-                    jnp.float32,
-                ),
-            )
-        else:
-            self.ssm_state = (jnp.zeros((0,)), jnp.zeros((0,)))
 
         self.slots: List[Optional[Request]] = [None] * batch_slots
         self.slot_len = np.zeros(batch_slots, np.int64)
@@ -243,12 +267,26 @@ class TieredEngine:
         return out
 
     # ------------------------------------------------------------ stepping
+    @_on_device
+    def compile_step(self):
+        """Compile the decode step for this engine's shapes ahead of the
+        first ``step`` — set-up time a caller can measure apart from serving
+        (with the persistent compile cache on, ``step`` then loads it).
+        Returns the ``jax.stages.Compiled`` executable; its ``as_text()``
+        shows whether the fused kernel (``tpu_custom_call``) is in it."""
+        tokens = jnp.zeros((self.bs, 1), jnp.int32)
+        return self._step_fn.lower(
+            self.params, tokens, self.cache.state, self.ssm_state
+        ).compile()
+
+    @_on_device
     def run(self, max_steps: int = 10_000) -> EngineStats:
         while (any(s is not None for s in self.slots) or self.queue) and self.stats.steps < max_steps:
             self._fill_slots()
             self.step()
         return self.finish()
 
+    @_on_device
     def step(self) -> None:
         """One externally-drivable engine step: decode every active slot,
         then advance the profile window. The frontend scheduler calls this
@@ -258,6 +296,7 @@ class TieredEngine:
         if self._steps_in_window >= self.ts.window_steps:
             self._end_window()
 
+    @_on_device
     def finish(self) -> EngineStats:
         """Drain in-flight cohorts and finalize the stats snapshot (idempotent
         — callable again after more stepping)."""
@@ -275,6 +314,7 @@ class TieredEngine:
         return self.stats
 
     # ----------------------------------------------- frontend slot control
+    @_on_device
     def start_request(self, slot: int, req: Request) -> None:
         """Place ``req`` into a specific FREE slot and prefill it — the
         frontend's admission-controlled alternative to the internal queue
@@ -285,6 +325,7 @@ class TieredEngine:
         self._prefill(slot, req)
         self.slots[slot] = req
 
+    @_on_device
     def preempt_slot(self, slot: int) -> PreemptedRequest:
         """Preemption-to-host-tier: demote the victim slot's device pages to
         their same-codec host tiers through the media pipeline (billed like
@@ -306,6 +347,7 @@ class TieredEngine:
         self.stats.preemptions += 1
         return pre
 
+    @_on_device
     def resume_into(self, slot: int, pre: PreemptedRequest) -> Request:
         """Swap a preempted request back into a free slot: parked host pages
         re-register and the previously device-resident ones ride swap-in
@@ -343,7 +385,7 @@ class TieredEngine:
             self.stats.re_prefill_tokens += s
         batch = {"tokens": jnp.asarray(req.prompt[None], jnp.int32)}
         state = self.model.init_cache(1, max(s + 1, self.pt))
-        logits, state = self.model.prefill(self.params, batch, state)
+        logits, state = self._prefill_fn(self.params, batch, state)
         # Page out everything except the tail that fits the recent window.
         n_full_pages = max((s - self.recent_window // 2) // self.pt, 0)
         k = np.asarray(state.k_cache.astype(jnp.float32))  # [L,1,S,KV,hd]
@@ -377,18 +419,14 @@ class TieredEngine:
         if cfg.family == "hybrid":
             # Recompute SSM states for this slot via recurrent prefill.
             dstate = self.model.init_cache(1, s + 1)
-            dstate = self.model._prefill_recurrent(self.params, batch, dstate, serve_rt.shr
-                                                   .activation_sharding(self._mesh_dummy(), ParallelConfig()))
+            dstate = self.model._prefill_recurrent(
+                self.params, batch, dstate,
+                serve_rt.shr.activation_sharding(self.mesh, ParallelConfig()))
             conv, sst = self.ssm_state
             self.ssm_state = (
                 conv.at[:, slot].set(dstate.conv_state[:, 0].astype(conv.dtype)),
                 sst.at[:, slot].set(dstate.ssm_state[:, 0]),
             )
-
-    def _mesh_dummy(self):
-        from repro.launch.mesh import make_mesh
-
-        return make_mesh((1, 1), ("data", "model"))
 
     def _decode_step(self):
         t0 = time.perf_counter()

@@ -65,7 +65,7 @@ from repro.kernels import ref as kref
 from repro.media.devices import adaptive_devices, make_queues
 from repro.media.pipeline import MigrationPipeline
 from repro.media.ringbuf import PinnedRing
-from repro.runtime.serve import TieredKVState, init_tiered_kv_state
+from repro.runtime.serve import CLASS_FIELDS, TieredKVState, init_tiered_kv_state
 
 # Placement indices (0 stays "uncompressed DRAM" for cost-model parity with
 # the paper; KV pages never occupy it — the recent window does).
@@ -459,29 +459,21 @@ class TieredKVCache:
         place in the shared class buffer; only row ownership moves."""
         return src in _DEVICE and dst in _DEVICE and self._bits[src] == self._bits[dst]
 
-    def _gather_rows(self, pool: str, layers, ps):
-        """Gather a pool cohort's payload/scale rows from its class buffer."""
+    def _gather_rows(self, pool: str, ps):
+        """Gather a pool cohort's payload/scale rows from its class buffer
+        (rows are global across layers: one row holds one layer's page)."""
         st = self.state
         cls = self._cls[pool]
-        return (
-            getattr(st, f"{cls}_k")[layers, ps],
-            getattr(st, f"{cls}_k_scales")[layers, ps],
-            getattr(st, f"{cls}_v")[layers, ps],
-            getattr(st, f"{cls}_v_scales")[layers, ps],
-        )
+        return tuple(getattr(st, f"{cls}_{f}")[ps] for f in CLASS_FIELDS)
 
-    def _scatter_rows(self, pool: str, layers, ps, k_pay, k_sc, v_pay, v_sc) -> None:
+    def _scatter_rows(self, pool: str, ps, k_pay, k_sc, v_pay, v_sc) -> None:
         st = self.state
         cls = self._cls[pool]
-        self.state = dataclasses.replace(
-            st,
-            **{
-                f"{cls}_k": getattr(st, f"{cls}_k").at[layers, ps].set(k_pay),
-                f"{cls}_k_scales": getattr(st, f"{cls}_k_scales").at[layers, ps].set(k_sc),
-                f"{cls}_v": getattr(st, f"{cls}_v").at[layers, ps].set(v_pay),
-                f"{cls}_v_scales": getattr(st, f"{cls}_v_scales").at[layers, ps].set(v_sc),
-            },
-        )
+        new = (k_pay, k_sc, v_pay, v_sc)
+        self.state = dataclasses.replace(st, **{
+            f"{cls}_{f}": getattr(st, f"{cls}_{f}").at[ps].set(x)
+            for f, x in zip(CLASS_FIELDS, new)
+        })
 
     def _exchange_rows(self, src: int, dst: int, rids, ps) -> None:
         """Transfer class-row ownership for a same-class cohort: each page's
@@ -583,7 +575,7 @@ class TieredKVCache:
             return
         ps = self._alloc_slot("warm", rid)
         kp, ks, vp, vs = self._quant_page(kpage, vpage, self._bits[WARM])
-        self._scatter_rows("warm", layer, ps, kp, ks, vp, vs)
+        self._scatter_rows("warm", ps, kp, ks, vp, vs)
         st = self.state
         n = int(st.warm_n[layer, slot])
         self.state = dataclasses.replace(
@@ -874,7 +866,7 @@ class TieredKVCache:
         if src in _DEVICE:
             pool = _POOL[src]
             ps = self._pool_slot[rids]
-            k_pay, k_sc, v_pay, v_sc = self._gather_rows(pool, layers, ps)
+            k_pay, k_sc, v_pay, v_sc = self._gather_rows(pool, ps)
             editor.remove(pool, layers, slots, ps)
             for x in ps:
                 self._free_slot(pool, int(x))
@@ -911,7 +903,7 @@ class TieredKVCache:
     def _scatter_device(self, dst, rids, layers, slots, k_pay, k_sc, v_pay, v_sc, editor):
         pool = _POOL[dst]
         new_ps = np.array([self._alloc_slot(pool, int(r)) for r in rids], np.int64)
-        self._scatter_rows(pool, layers, new_ps, k_pay, k_sc, v_pay, v_sc)
+        self._scatter_rows(pool, new_ps, k_pay, k_sc, v_pay, v_sc)
         editor.insert(pool, layers, slots, new_ps)
         self._pool_slot[rids] = new_ps
         self._set_placement(rids, dst)
@@ -951,7 +943,7 @@ class TieredKVCache:
         if src in _DEVICE:
             pool = _POOL[src]
             ps = self._pool_slot[rids]
-            kp, ks, vp, vs = self._gather_rows(pool, layers, ps)
+            kp, ks, vp, vs = self._gather_rows(pool, ps)
             payload = {
                 "k_pay": np.asarray(kp),
                 "k_sc": np.asarray(ks),
@@ -1133,9 +1125,7 @@ class TieredKVCache:
                 self._set_placement(srids, src)
                 actual[sp] = src
             else:
-                layers = srids // (self.bs * self.max_pages)
-                slots = (srids // self.max_pages) % self.bs
-                kp, ks, vp, vs = self._gather_rows(_POOL[src], layers, sps)
+                kp, ks, vp, vs = self._gather_rows(_POOL[src], sps)
                 sub = {
                     "k_pay": np.asarray(kp), "k_sc": np.asarray(ks),
                     "v_pay": np.asarray(vp), "v_sc": np.asarray(vs),
@@ -1271,12 +1261,10 @@ class TieredKVCache:
         if src in _DEVICE:
             cls, bits = self._cls[_POOL[src]], self._bits[src]
             k = kref.dequant_kv_page(
-                getattr(st, f"{cls}_k")[layer, ps],
-                getattr(st, f"{cls}_k_scales")[layer, ps], bits,
+                getattr(st, f"{cls}_k")[ps], getattr(st, f"{cls}_k_scales")[ps], bits
             )
             v = kref.dequant_kv_page(
-                getattr(st, f"{cls}_v")[layer, ps],
-                getattr(st, f"{cls}_v_scales")[layer, ps], bits,
+                getattr(st, f"{cls}_v")[ps], getattr(st, f"{cls}_v_scales")[ps], bits
             )
         else:
             kp, ks, vp, vs = self.host_pages[rid]
@@ -1332,7 +1320,7 @@ class TieredKVCache:
             pool = _POOL[dst]
             ps = self._alloc_slot(pool, rid)
             kp, ks, vp, vs = self._quant_page(k, v, self._bits[dst])
-            self._scatter_rows(pool, layer, ps, kp, ks, vp, vs)
+            self._scatter_rows(pool, ps, kp, ks, vp, vs)
             st = self.state
             n = int(getattr(st, f"{pool}_n")[layer, slot])
             st = dataclasses.replace(
@@ -1608,7 +1596,7 @@ class TieredKVCache:
                 np.asarray(getattr(st, f"{pool}_n")),
                 # Slots are global class-buffer rows; the lookup spans the
                 # whole class buffer (ranges interleave after exchanges).
-                getattr(st, f"{self._cls[pool]}_k").shape[1],
+                getattr(st, f"{self._cls[pool]}_k").shape[0],
                 live,
                 self._pool_slot,
             )

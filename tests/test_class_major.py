@@ -304,8 +304,7 @@ def test_same_class_blocking_move_is_pure_table_edit():
     coords = fill_cache(c, np.random.default_rng(0), 24)
     rids = np.array([c.rid(*x) for x in coords[:8]], np.int64)
     ps = c._pool_slot[rids].copy()
-    la = rids // (c.bs * c.max_pages)
-    payload = np.asarray(c.state.c8_k)[la, ps].copy()
+    payload = np.asarray(c.state.c8_k)[ps].copy()
     kd = c.kernel_dispatches
     c.migrate_batch(rids, np.full(rids.size, COLD, np.int64))
     check_table_invariants(c)
@@ -313,7 +312,7 @@ def test_same_class_blocking_move_is_pure_table_edit():
     assert (c.physical[rids] == COLD).all()
     np.testing.assert_array_equal(c._pool_slot[rids], ps)  # rows stayed put
     assert c.kernel_dispatches == kd  # no transcode dispatch
-    np.testing.assert_array_equal(np.asarray(c.state.c8_k)[la, ps], payload)
+    np.testing.assert_array_equal(np.asarray(c.state.c8_k)[ps], payload)
     # ...and back up, still by table edit.
     c.migrate_batch(rids, np.full(rids.size, WARM, np.int64))
     check_table_invariants(c)
@@ -448,8 +447,8 @@ def test_default_split_unchanged_by_class_major_layout():
     c = make_cache()
     assert c._alloc["warm"].base == 0 and c._alloc["cold"].base == 0
     assert c._cls == {"warm": "c8", "cold": "c4"}
-    assert c.state.c8_k.shape[1] == c._alloc["warm"].capacity
-    assert c.state.c4_k.shape[1] == c._alloc["cold"].capacity
+    assert c.state.c8_k.shape[0] == c._alloc["warm"].capacity
+    assert c.state.c4_k.shape[0] == c._alloc["cold"].capacity
     ids = [t.tid for t in c.manager.tierset.tiers]
     assert ids == ["C5", "C9", "C7", "C10"]
     c88 = make88()
@@ -457,8 +456,8 @@ def test_default_split_unchanged_by_class_major_layout():
     assert ids88 == ["C5", "C6", "C7", "C10"]
     # Same-class pools stack into one class buffer.
     assert (
-        c88.state.c8_k.shape[1]
+        c88.state.c8_k.shape[0]
         == c88._alloc["warm"].capacity + c88._alloc["cold"].capacity
     )
-    assert c88.state.c4_k.shape[1] == 1  # empty class: dummy row only
+    assert c88.state.c4_k.shape[0] == 1  # empty class: dummy row only
     assert c88._alloc["cold"].base == c88._alloc["warm"].capacity

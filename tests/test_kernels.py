@@ -38,10 +38,10 @@ SWEEP = [
 def test_quant_dequant_vs_ref(shape, bits, dtype):
     rng = np.random.default_rng(42)
     pages = _pages(rng, *shape, dtype=dtype)
-    pay_k, sc_k = quant_pages(pages, bits)
+    pay_k, sc_k = quant_pages(pages, bits, interpret=True)
     pay_r, sc_r = ref.quant_kv_page(pages, bits)
     np.testing.assert_allclose(np.asarray(sc_k), np.asarray(sc_r), rtol=1e-6)
-    deq_k = dequant_pages(pay_k, sc_k, bits, jnp.float32)
+    deq_k = dequant_pages(pay_k, sc_k, bits, jnp.float32, interpret=True)
     deq_r = ref.dequant_kv_page(pay_r, sc_r, bits)
     # <= 1 quantization step anywhere; >98% identical payloads.
     step = np.asarray(sc_r).max() * (1.0 if bits == 8 else 1.0)
@@ -72,7 +72,7 @@ def test_paged_attention_vs_ref(kv, heads, bits):
     q = jnp.asarray(rng.normal(0, 1, (B, heads, HD)), jnp.float32)
     table = jnp.asarray(rng.integers(0, P, (B, MP)), jnp.int32)
     n_pages = jnp.asarray([MP, 1, 0], jnp.int32)
-    out_k = paged_quant_attention(q, kp, ks, vp, vs, table, n_pages, bits)
+    out_k = paged_quant_attention(q, kp, ks, vp, vs, table, n_pages, bits, interpret=True)
     out_r = ref.paged_quant_attention(q, kp, ks, vp, vs, table, n_pages, bits)
     for name, a, b in zip(["out", "m", "l", "mass", "base"], out_k, out_r):
         np.testing.assert_allclose(
@@ -176,7 +176,7 @@ def test_transcode_pages_vs_ref_composition(shape, route):
     rng = np.random.default_rng(21)
     pages = _pages(rng, *shape, dtype=jnp.float32)
     pay, sc = ref.quant_kv_page(pages, src_bits)
-    k_pay, k_sc = transcode_pages(pay, sc, src_bits, dst_bits)
+    k_pay, k_sc = transcode_pages(pay, sc, src_bits, dst_bits, interpret=True)
     r_pay, r_sc = ref.quant_kv_page(ref.dequant_kv_page(pay, sc, src_bits), dst_bits)
     np.testing.assert_allclose(np.asarray(k_sc), np.asarray(r_sc), rtol=1e-6)
     # Payloads may differ only where a round-to-nearest tie flips: bound the
@@ -217,8 +217,8 @@ def test_transcode_roundtrip_error_bounded():
                        scale=draw_log_float(rng, 0.1, 10))
         pay8, sc8 = ref.quant_kv_page(pages, 8)
         x8 = np.asarray(ref.dequant_kv_page(pay8, sc8, 8))
-        pay4, sc4 = transcode_pages(pay8, sc8, 8, 4)
-        pay8b, sc8b = transcode_pages(pay4, sc4, 4, 8)
+        pay4, sc4 = transcode_pages(pay8, sc8, 8, 4, interpret=True)
+        pay8b, sc8b = transcode_pages(pay4, sc4, 4, 8, interpret=True)
         x8b = np.asarray(ref.dequant_kv_page(pay8b, sc8b, 8))
         bound = np.asarray(sc4)[..., None] * 0.51 + np.asarray(sc8b)[..., None] * 0.51 + 1e-6
         assert (np.abs(x8b - x8) <= bound).all(), i

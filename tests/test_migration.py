@@ -60,16 +60,15 @@ def logical_content(cache: TieredKVCache):
     for rid in np.where(cache._page_exists)[0]:
         rid = int(rid)
         loc = int(cache.physical[rid])
-        layer, _, _ = cache.rid_coords(rid)
         ps = int(cache._pool_slot[rid])
         if loc in (WARM, COLD):
             # Payloads live in the shared codec-class buffers; slots are
             # global class rows.
             cls = cache._cls["warm" if loc == WARM else "cold"]
-            item = (getattr(st, f"{cls}_k")[layer, ps],
-                    getattr(st, f"{cls}_k_scales")[layer, ps],
-                    getattr(st, f"{cls}_v")[layer, ps],
-                    getattr(st, f"{cls}_v_scales")[layer, ps])
+            item = (getattr(st, f"{cls}_k")[ps],
+                    getattr(st, f"{cls}_k_scales")[ps],
+                    getattr(st, f"{cls}_v")[ps],
+                    getattr(st, f"{cls}_v_scales")[ps])
         else:
             item = cache.host_pages[rid]
         out[rid] = (loc, tuple(np.asarray(x) for x in item))
