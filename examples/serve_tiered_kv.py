@@ -3,7 +3,8 @@
 The engine decodes against software-defined compressed KV tiers (warm int8 /
 cold int4 device pools + host tiers), with per-page attention-mass telemetry
 feeding the TierScape analytical placement model every window. Prints the
-paper's metrics: TCO savings, placement distribution, migrations, daemon tax.
+paper's metrics: TCO savings, placement distribution, migrations, and where
+the engine's steps spent their time (its program spans).
 
     PYTHONPATH=src python examples/serve_tiered_kv.py --requests 4
 """
@@ -19,6 +20,7 @@ from repro.configs.base import TierScapeRunConfig
 from repro.launch import compile_cache
 from repro.models import Model
 from repro.serving import TieredEngine
+from repro.serving.spans import totals
 from repro.serving.kv_cache import COLD, HOST4, HOST8, WARM
 
 
@@ -72,15 +74,21 @@ def main() -> None:
         reqs.append(eng.submit(rng.integers(1, cfg.vocab_size, plen),
                                max_new_tokens=args.new_tokens))
 
+    eng.spans.start()
     t0 = time.time()
     stats = eng.run(max_steps=args.requests * args.new_tokens * 2)
     wall = time.time() - t0
+    spans = totals(eng.spans.stop())
 
     print(f"arch={args.arch} policy={args.policy} alpha={args.alpha}")
     print(f"completed {stats.completed}/{args.requests} requests in "
           f"{stats.steps} engine steps ({wall:.1f}s wall)")
     print(f"windows={stats.windows} migrations={stats.migrations} "
-          f"daemon_s={stats.daemon_s:.2f} overlapped_steps={stats.overlapped_steps}")
+          f"overlapped_steps={stats.overlapped_steps}")
+    step, wait = spans["tkv.step"], spans["tkv.wait"]
+    print(f"engine steps: {step.total_ns * 1e-9:.2f}s, of which waiting for the device "
+          f"{wait.total_ns * 1e-9:.2f}s and host work {(step.total_ns - wait.total_ns) * 1e-9:.2f}s "
+          f"({step.self_ns * 1e-9:.2f}s outside the step's named spans)")
     if prefetch:
         print(f"prefetch: staged={stats.prefetch_staged} "
               f"hits={stats.prefetch_hits} misses={stats.prefetch_misses}")

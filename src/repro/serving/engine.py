@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Optional
 
 import jax
@@ -82,8 +81,6 @@ class EngineStats:
     # (fused: n_layers per step, O(1) in tier count; per-pool oracle:
     # n_layers * n_pools).
     attn_launches: int = 0
-    decode_s: float = 0.0
-    daemon_s: float = 0.0
     tco_savings_pct: float = 0.0
     completed_by_tenant: Dict[int, int] = dataclasses.field(default_factory=dict)
     tco_savings_by_tenant: Dict[int, float] = dataclasses.field(default_factory=dict)
@@ -193,6 +190,9 @@ class TieredEngine:
             )
         )
 
+        # Program spans (``serving/spans.py``), shared with the cache.
+        self.spans = self.cache.spans
+
         self.slots: List[Optional[Request]] = [None] * batch_slots
         self.slot_len = np.zeros(batch_slots, np.int64)
         self.queue: List[Request] = []
@@ -291,18 +291,18 @@ class TieredEngine:
         """One externally-drivable engine step: decode every active slot,
         then advance the profile window. The frontend scheduler calls this
         directly, interleaving placement/preemption between steps."""
-        self._decode_step()
-        self._steps_in_window += 1
-        if self._steps_in_window >= self.ts.window_steps:
-            self._end_window()
+        with self.spans.span("tkv.step"):
+            self._decode_step()
+            self._steps_in_window += 1
+            if self._steps_in_window >= self.ts.window_steps:
+                self._end_window()
 
     @_on_device
     def finish(self) -> EngineStats:
         """Drain in-flight cohorts and finalize the stats snapshot (idempotent
         — callable again after more stepping)."""
-        t0 = time.perf_counter()
-        self.cache.drain_migrations()
-        self.stats.daemon_s += time.perf_counter() - t0
+        with self.spans.span("tkv.finish"):
+            self.cache.drain_migrations()
         self.stats.tco_savings_pct = max(
             self.stats.tco_savings_pct, self.cache.tco_savings_pct()
         )
@@ -322,7 +322,8 @@ class TieredEngine:
         if self.slots[slot] is not None:
             raise ValueError(f"start_request: slot {slot} is occupied")
         self.cache.set_slot_tenant(slot, req.tenant)
-        self._prefill(slot, req)
+        with self.spans.span("tkv.prefill", rid=req.rid):
+            self._prefill(slot, req)
         self.slots[slot] = req
 
     @_on_device
@@ -335,8 +336,9 @@ class TieredEngine:
         req = self.slots[slot]
         if req is None or req.done:
             raise ValueError(f"preempt_slot: slot {slot} has no active request")
-        levels = self.cache.demote_slot_to_host(slot)
-        parked = self.cache.park_slot(slot, restore_levels=levels)
+        with self.spans.span("tkv.preempt", rid=req.rid):
+            levels = self.cache.demote_slot_to_host(slot)
+            parked = self.cache.park_slot(slot, restore_levels=levels)
         pre = PreemptedRequest(request=req, parked=parked)
         if self.cfg.family == "hybrid":
             conv, sst = self.ssm_state
@@ -354,7 +356,8 @@ class TieredEngine:
         cohorts home. No prompt token is ever recomputed."""
         if self.slots[slot] is not None:
             raise ValueError(f"resume_into: slot {slot} is occupied")
-        restored = self.cache.restore_slot(slot, pre.parked)
+        with self.spans.span("tkv.resume", rid=pre.request.rid):
+            restored = self.cache.restore_slot(slot, pre.parked)
         if self.cfg.family == "hybrid" and pre.ssm_conv is not None:
             conv, sst = self.ssm_state
             self.ssm_state = (
@@ -384,12 +387,13 @@ class TieredEngine:
             # wasted recompute the preemption path exists to avoid.
             self.stats.re_prefill_tokens += s
         batch = {"tokens": jnp.asarray(req.prompt[None], jnp.int32)}
-        state = self.model.init_cache(1, max(s + 1, self.pt))
-        logits, state = self._prefill_fn(self.params, batch, state)
+        with self.spans.span("tkv.prefill.compute", rid=req.rid):
+            state = self.model.init_cache(1, max(s + 1, self.pt))
+            logits, state = self._prefill_fn(self.params, batch, state)
+            k = np.asarray(state.k_cache.astype(jnp.float32))  # [L,1,S,KV,hd]
+            v = np.asarray(state.v_cache.astype(jnp.float32))
         # Page out everything except the tail that fits the recent window.
         n_full_pages = max((s - self.recent_window // 2) // self.pt, 0)
-        k = np.asarray(state.k_cache.astype(jnp.float32))  # [L,1,S,KV,hd]
-        v = np.asarray(state.v_cache.astype(jnp.float32))
         entries = [
             (layer, slot, page)
             for layer in range(self.la) for page in range(n_full_pages)
@@ -399,7 +403,8 @@ class TieredEngine:
                            for layer, _, page in entries])
             vp = np.stack([v[layer, 0, page * self.pt:(page + 1) * self.pt]
                            for layer, _, page in entries])
-            self.cache.append_pages(entries, jnp.asarray(kp), jnp.asarray(vp))
+            with self.spans.span("tkv.prefill.page_in", rid=req.rid):
+                self.cache.append_pages(entries, jnp.asarray(kp), jnp.asarray(vp))
         # Remaining tail into the recent window.
         tail = slice(n_full_pages * self.pt, s)
         tlen = s - n_full_pages * self.pt
@@ -429,44 +434,48 @@ class TieredEngine:
             )
 
     def _decode_step(self):
-        t0 = time.perf_counter()
-        tokens = np.zeros((self.bs, 1), np.int32)
-        for i, req in enumerate(self.slots):
-            if req is not None and req.out_tokens:
-                tokens[i, 0] = req.out_tokens[-1]
-        logits, tkv, ssm_state, telemetry = self._step_fn(
-            self.params, jnp.asarray(tokens), self.cache.state, self.ssm_state
-        )
+        span = self.spans.span
+        with span("tkv.dispatch"):
+            tokens = np.zeros((self.bs, 1), np.int32)
+            for i, req in enumerate(self.slots):
+                if req is not None and req.out_tokens:
+                    tokens[i, 0] = req.out_tokens[-1]
+            logits, tkv, ssm_state, telemetry = self._step_fn(
+                self.params, jnp.asarray(tokens), self.cache.state, self.ssm_state
+            )
         self.cache.state = tkv
         self.ssm_state = ssm_state
-        self.stats.decode_s += time.perf_counter() - t0
-
-        t1 = time.perf_counter()
+        # The telemetry fold below reads the step's outputs first; waiting
+        # for them here puts that wait in a span of its own, no earlier.
+        with span("tkv.wait"):
+            jax.block_until_ready((logits, telemetry))
         self.cache.record_telemetry(telemetry)
         # Advance in-flight migration cohorts by one phase: decode retired a
         # step while migration ran — the overlap the async pipeline buys.
         if self.cache.pipeline.busy:
-            self.cache.pipeline.tick()
+            with span("tkv.pipeline"):
+                self.cache.pipeline.tick()
             self.stats.overlapped_steps += 1
         else:
             # Idle media path: spend the step on speculative prefetch of
             # warming host pages (no-op unless ts.prefetch enabled).
-            self.cache.prefetch_tick()
-        self.stats.daemon_s += time.perf_counter() - t1
+            with span("tkv.prefetch"):
+                self.cache.prefetch_tick()
 
-        next_tok = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            req.out_tokens.append(int(next_tok[i]))
-            self.slot_len[i] += 1
-            if len(req.out_tokens) >= req.max_new_tokens:
-                req.done = True
-                self.stats.completed += 1
-                self.stats.completed_by_tenant[req.tenant] = (
-                    self.stats.completed_by_tenant.get(req.tenant, 0) + 1
-                )
-                self._release_slot(i)
+        with span("tkv.sample"):
+            next_tok = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                req.out_tokens.append(int(next_tok[i]))
+                self.slot_len[i] += 1
+                if len(req.out_tokens) >= req.max_new_tokens:
+                    req.done = True
+                    self.stats.completed += 1
+                    self.stats.completed_by_tenant[req.tenant] = (
+                        self.stats.completed_by_tenant.get(req.tenant, 0) + 1
+                    )
+                    self._release_slot(i)
         self.stats.steps += 1
         self._maybe_page_out_recent()
 
@@ -482,41 +491,42 @@ class TieredEngine:
         ]
         if not full:
             return
-        k = np.asarray(st.recent_k.astype(jnp.float32))  # [L,B,R,KV,hd]
-        v = np.asarray(st.recent_v.astype(jnp.float32))
-        # Page out all layers x full-slots x pages in one batched append.
-        entries, kps, vps = [], [], []
-        shift = np.zeros(self.bs, np.int64)
-        for i in full:
-            # Move floor(rl/pt)-1 pages out, keep the newest tokens dense
-            # (n_out >= 1: the window is full, something must leave).
-            n_out = max(int(rl[i]) // self.pt - 1, 1)
-            shift[i] = n_out * self.pt
-        for layer in range(self.la):
+        with self.spans.span("tkv.page_out"):
+            k = np.asarray(st.recent_k.astype(jnp.float32))  # [L,B,R,KV,hd]
+            v = np.asarray(st.recent_v.astype(jnp.float32))
+            # Page out all layers x full-slots x pages in one batched append.
+            entries, kps, vps = [], [], []
+            shift = np.zeros(self.bs, np.int64)
             for i in full:
-                start_tok = int(self.slot_len[i]) - int(rl[i])
-                for p in range(int(shift[i]) // self.pt):
-                    page_idx = (start_tok + p * self.pt) // self.pt
-                    sl = slice(p * self.pt, (p + 1) * self.pt)
-                    entries.append((layer, i, page_idx))
-                    kps.append(k[layer, i, sl])
-                    vps.append(v[layer, i, sl])
-        if entries:
-            self.cache.append_pages(
-                entries, jnp.asarray(np.stack(kps)), jnp.asarray(np.stack(vps))
+                # Move floor(rl/pt)-1 pages out, keep the newest tokens dense
+                # (n_out >= 1: the window is full, something must leave).
+                n_out = max(int(rl[i]) // self.pt - 1, 1)
+                shift[i] = n_out * self.pt
+            for layer in range(self.la):
+                for i in full:
+                    start_tok = int(self.slot_len[i]) - int(rl[i])
+                    for p in range(int(shift[i]) // self.pt):
+                        page_idx = (start_tok + p * self.pt) // self.pt
+                        sl = slice(p * self.pt, (p + 1) * self.pt)
+                        entries.append((layer, i, page_idx))
+                        kps.append(k[layer, i, sl])
+                        vps.append(v[layer, i, sl])
+            if entries:
+                self.cache.append_pages(
+                    entries, jnp.asarray(np.stack(kps)), jnp.asarray(np.stack(vps))
+                )
+            st = self.cache.state
+            # Per-slot roll, device-side: row b reads from (j + shift[b]) % R.
+            r = st.recent_k.shape[2]
+            idx = (jnp.arange(r, dtype=jnp.int32)[None, :]
+                   + jnp.asarray(shift, jnp.int32)[:, None]) % r  # [B, R]
+            gidx = idx[None, :, :, None, None]
+            self.cache.state = dataclasses.replace(
+                st,
+                recent_k=jnp.take_along_axis(st.recent_k, gidx, axis=2),
+                recent_v=jnp.take_along_axis(st.recent_v, gidx, axis=2),
+                recent_len=st.recent_len - jnp.asarray(shift, jnp.int32),
             )
-        st = self.cache.state
-        # Per-slot roll, device-side: row b reads from (j + shift[b]) % R.
-        r = st.recent_k.shape[2]
-        idx = (jnp.arange(r, dtype=jnp.int32)[None, :]
-               + jnp.asarray(shift, jnp.int32)[:, None]) % r  # [B, R]
-        gidx = idx[None, :, :, None, None]
-        self.cache.state = dataclasses.replace(
-            st,
-            recent_k=jnp.take_along_axis(st.recent_k, gidx, axis=2),
-            recent_v=jnp.take_along_axis(st.recent_v, gidx, axis=2),
-            recent_len=st.recent_len - jnp.asarray(shift, jnp.int32),
-        )
 
     def _release_slot(self, slot: int):
         """Request finished: free its pages everywhere (batched)."""
@@ -531,18 +541,17 @@ class TieredEngine:
         )
 
     def _end_window(self):
-        t0 = time.perf_counter()
-        plan, moved = self.cache.end_window()
-        self.stats.daemon_s += time.perf_counter() - t0
-        self.stats.migrations += moved
-        self.stats.windows += 1
-        self._steps_in_window = 0
-        # Snapshot TCO savings while pages are live (completion frees them).
-        self.stats.tco_savings_pct = max(
-            self.stats.tco_savings_pct, self.cache.tco_savings_pct()
-        )
-        for t in {r.tenant for r in self.slots if r is not None}:
-            self.stats.tco_savings_by_tenant[t] = max(
-                self.stats.tco_savings_by_tenant.get(t, 0.0),
-                self.cache.tco_savings_pct(tenant=t),
+        with self.spans.span("tkv.end_window"):
+            plan, moved = self.cache.end_window()
+            self.stats.migrations += moved
+            self.stats.windows += 1
+            self._steps_in_window = 0
+            # Snapshot TCO savings while pages are live (completion frees them).
+            self.stats.tco_savings_pct = max(
+                self.stats.tco_savings_pct, self.cache.tco_savings_pct()
             )
+            for t in {r.tenant for r in self.slots if r is not None}:
+                self.stats.tco_savings_by_tenant[t] = max(
+                    self.stats.tco_savings_by_tenant.get(t, 0.0),
+                    self.cache.tco_savings_pct(tenant=t),
+                )

@@ -66,6 +66,7 @@ from repro.media.devices import adaptive_devices, make_queues
 from repro.media.pipeline import MigrationPipeline
 from repro.media.ringbuf import PinnedRing
 from repro.runtime.serve import CLASS_FIELDS, TieredKVState, init_tiered_kv_state
+from repro.serving.spans import SpanRecorder
 
 # Placement indices (0 stays "uncompressed DRAM" for cost-model parity with
 # the paper; KV pages never occupy it — the recent window does).
@@ -342,6 +343,8 @@ class TieredKVCache:
         # once fusion is on.
         self.attn_launches = 0
         self.decode_steps_recorded = 0
+        # Program spans (``serving/spans.py``); an engine shares this one.
+        self.spans = SpanRecorder()
 
         # --- backing-media subsystem -----------------------------------
         # One MediaQueue per distinct device (shared-bandwidth accounting),
@@ -1550,12 +1553,13 @@ class TieredKVCache:
         quality cost of the best-TCO tiers (tracked, reported) and feeding
         it to the placement model would break oracle-identical placements.
         """
-        self.manager.record_access_counts(self._fold_telemetry(telemetry) * 1000.0)
-        host_mass = telemetry.get("host")
-        if host_mass is not None:
-            folded = self._fold_host_mass(host_mass)
-            self.quality_skipped_mass += float(folded.sum())
-            self.manager.record_host_mass(folded * 1000.0)
+        with self.spans.span("tkv.telemetry"):
+            self.manager.record_access_counts(self._fold_telemetry(telemetry) * 1000.0)
+            host_mass = telemetry.get("host")
+            if host_mass is not None:
+                folded = self._fold_host_mass(host_mass)
+                self.quality_skipped_mass += float(folded.sum())
+                self.manager.record_host_mass(folded * 1000.0)
         # Decode-side dispatch proxy: one fused launch per layer per step,
         # O(tiers) only when the per-pool oracle path is toggled on.
         self.attn_launches += self.la * kops.decode_launches_per_step(
@@ -1749,16 +1753,19 @@ class TieredKVCache:
         previous window's stragglers are drained first so the placement
         model never plans over in-flight pages.
         """
-        if self.pipeline.busy:
-            self.pipeline.drain()
-        if self.prefetch_enabled:
-            # Speculation meets reality: finish staged speculative cohorts
-            # into the held store before the plan is computed.
-            self.pipeline.finish_speculative()
+        span = self.spans.span
+        with span("tkv.drain"):
+            if self.pipeline.busy:
+                self.pipeline.drain()
+            if self.prefetch_enabled:
+                # Speculation meets reality: finish staged speculative cohorts
+                # into the held store before the plan is computed.
+                self.pipeline.finish_speculative()
         self._observe_adaptive_media()
         if self.fault_plan is not None:
             self._advance_fault_window()
-        plan = self.manager.end_window()
+        with span("tkv.plan"):
+            plan = self.manager.end_window()
         self._prefetch_window_emitted = False
         if plan.regions.size == 0:
             if self.prefetch_enabled:
@@ -1788,23 +1795,25 @@ class TieredKVCache:
             self.fault_deferred_pages += int(bad.sum())
             regions, dst = regions[~bad], dst[~bad]
         if self.async_migration:
-            cohorts = self.plan_cohorts(regions, dst)
-            prestaged: Dict[int, Dict[str, np.ndarray]] = {}
-            if self.prefetch_enabled:
-                # Claim held pages the plan confirmed (hits — their demand
-                # stage pays no source read); everything else was
-                # mispredicted and is discarded, returning the ring credits.
-                for crids, s, _d in cohorts:
-                    if s not in _DEVICE:
-                        prestaged.update(self.pipeline.claim_prefetched(crids, s))
-                self.pipeline.discard_speculative()
-            self._pending_reconcile.append(np.asarray(plan.regions, np.int64))
-            queued = self.pipeline.submit(cohorts, prestaged=prestaged or None)
+            with span("tkv.submit"):
+                cohorts = self.plan_cohorts(regions, dst)
+                prestaged: Dict[int, Dict[str, np.ndarray]] = {}
+                if self.prefetch_enabled:
+                    # Claim held pages the plan confirmed (hits — their demand
+                    # stage pays no source read); everything else was
+                    # mispredicted and is discarded, returning the ring credits.
+                    for crids, s, _d in cohorts:
+                        if s not in _DEVICE:
+                            prestaged.update(self.pipeline.claim_prefetched(crids, s))
+                    self.pipeline.discard_speculative()
+                self._pending_reconcile.append(np.asarray(plan.regions, np.int64))
+                queued = self.pipeline.submit(cohorts, prestaged=prestaged or None)
             if not self.pipeline.busy:
                 # Empty plan after pre-passes: reconcile immediately.
                 self.on_pipeline_drained()
             return plan, queued
-        moved = self.migrate_batch(regions, dst)
+        with span("tkv.submit"):
+            moved = self.migrate_batch(regions, dst)
         # The executor wrote actual placements (incl. spills) back into
         # manager.placement so the cost model prices reality; also reconcile
         # planned no-ops (e.g. DRAM-recommended pages already sitting warm)
