@@ -24,9 +24,9 @@ served path and is not built for the TPU lowering.
 ``page_hotness`` turns per-page mass telemetry into the normalized hotness
 the TierScape manager consumes. ``launch_count``/``reset_launch_count``
 count actual Pallas launches issued through this module (the benchmark /
-baseline-guard metric); ``decode_launches_per_step`` is the modeled
-launches-per-decode-step proxy the serving cache bills, valid on the ref
-path too.
+baseline-guard metric); ``decode_launches_per_step`` and
+``decode_walk_slots`` are the modeled launches and walked page slots per
+decode step that the serving cache bills, valid on the ref path too.
 """
 
 from __future__ import annotations
@@ -122,6 +122,18 @@ def decode_launches_per_step(n_pools: int) -> int:
     if _USE_FUSED:
         return 1
     return int(n_pools)
+
+
+def decode_walk_slots(held: np.ndarray, max_pages: int, n_pools: int) -> int:
+    """Modeled page slots one decode step's attention walks, given the
+    pages each (layer, batch row) holds (pools and host sentinels
+    together): the fused kernel steps through exactly the held pages; the
+    per-pool path walks every column of each pool's ``max_pages``-wide
+    table. Like ``decode_launches_per_step``, valid on the ref path too."""
+    held = np.asarray(held, np.int64)
+    if _USE_FUSED:
+        return int(held.sum())
+    return int(n_pools) * max_pages * held.size
 
 
 def quant_pages(pages: Array, bits: int) -> Tuple[Array, Array]:
@@ -273,6 +285,22 @@ def _check_class_bounds(uni_slot, uni_tier, rows8: int, rows4: int) -> None:
                 )
 
 
+def _check_walk_bound(uni_tier, walk: int) -> None:
+    """Eager-path guard: the fused kernel walks at most ``walk`` held pages
+    per row (``paged_attention.compact_walk``), so a row holding more valid
+    slots would silently lose the excess. Like ``_check_class_bounds``,
+    tier codes are data: traced callers are exempt."""
+    if isinstance(uni_tier, jax.core.Tracer):
+        return
+    held = (np.asarray(uni_tier) >= 0).sum(axis=1)
+    if held.size and int(held.max()) > walk:
+        raise ValueError(
+            f"a unified-table row holds {int(held.max())} pages but the "
+            f"fused kernel's walk bound is {walk} (rows "
+            f"{np.flatnonzero(held > walk).tolist()})"
+        )
+
+
 def _unified_operands(q, pools, recent_k, host):
     """Assemble the megakernel's operands from N tier pools: two codec-class
     payload buffers plus the unified page table.
@@ -335,12 +363,15 @@ def _unified_operands(q, pools, recent_k, host):
     return (k8, s8k, v8, s8v, k4, s4k, v4, s4v, summary, uni_slot, uni_tier, t, layout)
 
 
-def _fused_path(q, pools, recent_k, recent_v, recent_len, host, with_telemetry):
+def _fused_path(q, pools, recent_k, recent_v, recent_len, host, with_telemetry,
+                max_pages):
     b = q.shape[0]
     rlen = jnp.broadcast_to(jnp.asarray(recent_len, jnp.int32), (b,))
     if _USE_PALLAS:
         (k8, s8k, v8, s8v, k4, s4k, v4, s4v, summary,
          uni_slot, uni_tier, t, layout) = _unified_operands(q, pools, recent_k, host)
+        walk = uni_slot.shape[1] if max_pages is None else int(max_pages)
+        _check_walk_bound(uni_tier, walk)
         _count_launch()
         # ``t`` is the launch's single validated page-tokens value — the
         # sentinel mass multiplier and the device pools' page shape agree
@@ -348,7 +379,7 @@ def _fused_path(q, pools, recent_k, recent_v, recent_len, host, with_telemetry):
         out, m, l, mass, base = fused_attn_kernel(
             q, k8, s8k, v8, s8v, k4, s4k, v4, s4v, summary,
             recent_k, recent_v, uni_slot, uni_tier, rlen, page_tokens=t,
-            interpret=not compiled(),
+            walk=walk, interpret=not compiled(),
         )
         if not with_telemetry:
             return out
@@ -376,6 +407,7 @@ def tiered_decode_attention(
     cfg=None,
     with_telemetry: bool = False,
     host: Optional[Dict[str, Array]] = None,
+    max_pages: Optional[int] = None,
 ):
     """Attention over tiered compressed KV pools + dense recent window.
 
@@ -387,11 +419,15 @@ def tiered_decode_attention(
     pages — telemetry for the prefetch predictor, never part of the output.
 
     Fused mode (default): one Pallas launch per call, O(1) in tier count.
+    ``max_pages`` bounds the pages one row holds across all pools and host
+    sentinels (the serving cache passes one sequence's page count); the
+    kernel's walk is sized by it, and defaults to the unified table width.
     ``use_fused(False)``: one launch per pool + post-hoc merge (the
     equivalence oracle; outputs/hotness match to fp32 tolerance).
     """
     if _USE_FUSED:
-        return _fused_path(q, pools, recent_k, recent_v, recent_len, host, with_telemetry)
+        return _fused_path(q, pools, recent_k, recent_v, recent_len, host, with_telemetry,
+                           max_pages)
     parts = [_ref.dense_recent_attention(q, recent_k, recent_v, recent_len)]
     masses = {}
     for name in sorted(pools):

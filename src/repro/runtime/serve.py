@@ -354,9 +354,13 @@ def make_tiered_decode_step(
         if use_kernels:
             # Fused megakernel: ONE Pallas launch for all pools + host
             # sentinels + the recent window (see kernels/ops.py).
+            # Each table is one sequence's max_pages wide, and a sequence's
+            # pages split across warm, cold and host never exceed that: the
+            # kernel walks at most that many pages per slot.
             out, hot = kops.tiered_decode_attention(
                 q[:, 0], pools, recent_k, recent_v, recent_len + 1, cfg,
                 with_telemetry=True, host=host,
+                max_pages=layer_tkv["warm_table"].shape[-1],
             )
         elif use_sp:
             sp = _make_sp(b)

@@ -81,6 +81,11 @@ class EngineStats:
     # (fused: n_layers per step, O(1) in tier count; per-pool oracle:
     # n_layers * n_pools).
     attn_launches: int = 0
+    # The fused kernel's page walk, billed by the cache from host-side page
+    # counts: page slots its grid visits and pages it attends (pool pages
+    # and host sentinels), summed over layers and steps.
+    attn_walk_slots: int = 0
+    attn_pages: int = 0
     tco_savings_pct: float = 0.0
     completed_by_tenant: Dict[int, int] = dataclasses.field(default_factory=dict)
     tco_savings_by_tenant: Dict[int, float] = dataclasses.field(default_factory=dict)
@@ -311,6 +316,8 @@ class TieredEngine:
         self.stats.prefetch_hits = pipe.prefetch_hits
         self.stats.prefetch_misses = pipe.prefetch_misses
         self.stats.attn_launches = self.cache.attn_launches
+        self.stats.attn_walk_slots = self.cache.attn_walk_slots
+        self.stats.attn_pages = self.cache.attn_pages
         return self.stats
 
     # ----------------------------------------------- frontend slot control

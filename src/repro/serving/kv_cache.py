@@ -342,6 +342,12 @@ class TieredKVCache:
         # oracle — so WindowStats/TCO reports stop billing O(tiers) launches
         # once fusion is on.
         self.attn_launches = 0
+        # The same step's attention walk, from host-side page counts: page
+        # slots the kernel's grid visits and pages it attends (device-pool
+        # pages plus host sentinels) — walked/held is the walk's overhead.
+        # A rid's (layer, slot) row is ``rid // max_pages``.
+        self.attn_walk_slots = 0
+        self.attn_pages = 0
         self.decode_steps_recorded = 0
         # Program spans (``serving/spans.py``); an engine shares this one.
         self.spans = SpanRecorder()
@@ -1565,6 +1571,12 @@ class TieredKVCache:
         self.attn_launches += self.la * kops.decode_launches_per_step(
             n_pools=len(_POOL)
         )
+        held_rids = np.flatnonzero(
+            self._page_exists & (self.physical >= WARM) & (self.physical <= HOST4)
+        )
+        held = np.bincount(held_rids // self.max_pages, minlength=self.la * self.bs)
+        self.attn_walk_slots += kops.decode_walk_slots(held, self.max_pages, len(_POOL))
+        self.attn_pages += int(held_rids.size)
         self.decode_steps_recorded += 1
 
     def _fold_table_mass(self, counts, mass, table, nvec, cap, live, slot_of) -> None:

@@ -4,8 +4,10 @@ Covers the megakernel contract: fused == per-pool == pure-jnp ref for both
 outputs and normalized page hotness (fp32 tolerance) across mixed int8/int4
 pools; exactly one Pallas launch per decode step independent of tier count;
 empty-pool and all-host-pages edge cases; host sentinel would-have-touched
-mass matching the ref oracle; the in-engine host-mass route into the
-prefetch predictor; and placement neutrality of the host telemetry.
+mass matching the ref oracle; the compacted page walk (holes, unequal rows,
+the walk bound, several pages per grid step) against the ref in output and
+raw per-slot mass/base; the in-engine host-mass route into the prefetch
+predictor; and placement neutrality of the host telemetry.
 """
 
 import numpy as np
@@ -13,7 +15,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, paged_attention, ref
 
 B, H, KV, HD, T, R = 2, 8, 2, 32, 8, 6
 TOL = dict(rtol=2e-4, atol=2e-4)
@@ -146,6 +148,111 @@ def test_empty_pool_and_all_host_edges():
     )
     assert float(np.abs(np.asarray(hot["warm"])).sum()) == 0.0
     assert float(np.asarray(hot["host"]).sum()) > 0.0
+
+
+def _walk_case(name, rng):
+    """(pools, host, walk) for one compacted-walk case. Unified rows are
+    warm | cold | host columns; a pool's valid entries are a prefix of its
+    table, so partly filled pools leave holes inside the unified row."""
+    if name == "holes_unequal_rows":
+        pools = {"warm": _mk_pool(rng, 9, 8, 6, [5, 1]),
+                 "cold": _mk_pool(rng, 9, 4, 6, [2, 6])}
+        return pools, _mk_host(rng, mp=4, n=(3, 0)), None
+    if name == "mixed_int8_int4_rows":
+        pools = {f"t{i}": _mk_pool(rng, 7, (8, 4)[i % 2], 4, [(3, 1, 4, 2)[i], (0, 4, 2, 3)[i]])
+                 for i in range(4)}
+        return pools, _mk_host(rng, mp=3, n=(2, 1)), None
+    if name == "all_host_and_empty_rows":
+        pools = {"warm": _mk_pool(rng, 4, 8, 3, [0, 0]),
+                 "cold": _mk_pool(rng, 4, 4, 3, [0, 0])}
+        return pools, _mk_host(rng, mp=5, n=(5, 0)), None
+    if name == "row_at_walk_bound":
+        pools = {"warm": _mk_pool(rng, 8, 8, 6, [6, 2]),
+                 "cold": _mk_pool(rng, 8, 4, 6, [3, 1])}
+        return pools, _mk_host(rng, mp=3, n=(2, 0)), 11
+    assert name == "walk_bound_below_table_width"
+    # As served: the bound is one sequence's pages, a third of the width.
+    pools = {"warm": _mk_pool(rng, 8, 8, 6, [6, 3]),
+             "cold": _mk_pool(rng, 8, 4, 6, [4, 5])}
+    return pools, _mk_host(rng, mp=6, n=(0, 2)), 12
+
+
+WALK_CASES = ["holes_unequal_rows", "mixed_int8_int4_rows", "all_host_and_empty_rows",
+              "row_at_walk_bound", "walk_bound_below_table_width"]
+
+
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_compacted_walk_matches_ref(case):
+    """The kernel walks only held pages: output, merged logsumexp and the raw
+    per-slot mass/base at the unified layout match the jnp oracle, with
+    holes, unequal rows, all-host and empty rows, a row exactly at the
+    walk bound and a bound below the table width."""
+    rng = np.random.default_rng(WALK_CASES.index(case) + 40)
+    pools, host, walk = _walk_case(case, rng)
+    q, rk, rv, rlen = _inputs(rng)
+    (k8, s8k, v8, s8v, k4, s4k, v4, s4v, summary,
+     uni_slot, uni_tier, t, layout) = ops._unified_operands(q, pools, rk, host)
+    held = (np.asarray(uni_tier) >= 0).sum(axis=1)
+    if case == "row_at_walk_bound":
+        assert held.max() == walk
+    out, m, l, mass, base = paged_attention.fused_tiered_attention(
+        q, k8, s8k, v8, s8v, k4, s4k, v4, s4v, summary, rk, rv, uni_slot, uni_tier,
+        rlen, page_tokens=t, walk=walk, interpret=True)
+    rout, rm, rl, masses = ref.fused_tiered_attention(q, pools, rk, rv, rlen, host=host)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(rout), **TOL)
+    # The merged logsumexp; (m, l) alone differ by convention where a pool
+    # is empty (the oracle merges its m=0 partial).
+    np.testing.assert_allclose(np.asarray(m + jnp.log(l)), np.asarray(rm + jnp.log(rl)), **TOL)
+    assert set(layout) == set(masses)
+    for name, (lo, hi) in layout.items():
+        rmass, rbase = masses[name]
+        np.testing.assert_allclose(np.asarray(mass[:, lo:hi]), np.asarray(rmass),
+                                   err_msg=name, **TOL)
+        np.testing.assert_allclose(np.asarray(base[:, lo:hi]), np.asarray(rbase),
+                                   err_msg=name, **TOL)
+    # Slots that hold no page read exactly 0 mass at a NEG_INF base.
+    empty = np.asarray(uni_tier) < 0
+    assert (np.asarray(mass)[empty] == 0.0).all()
+    assert (np.asarray(base)[empty] == paged_attention.NEG_INF).all()
+
+
+def test_walk_bound_check_raises():
+    """An eager call whose rows hold more valid slots than the walk bound
+    raises instead of silently dropping pages."""
+    rng = np.random.default_rng(9)
+    pools = {"warm": _mk_pool(rng, 6, 8, 4, [4, 1]),
+             "cold": _mk_pool(rng, 6, 4, 4, [2, 1])}
+    host = _mk_host(rng, mp=3, n=(1, 0))
+    q, rk, rv, rlen = _inputs(rng)
+    ops.tiered_decode_attention(q, pools, rk, rv, rlen, with_telemetry=True,
+                                host=host, max_pages=7)
+    with pytest.raises(ValueError, match="walk bound is 6"):
+        ops.tiered_decode_attention(q, pools, rk, rv, rlen, with_telemetry=True,
+                                    host=host, max_pages=6)
+
+
+def test_compact_walk_and_scatter_round_trip():
+    """``compact_walk`` lists held columns in column order with their slot
+    and tier; ``scatter_walk`` puts walk-ordered values back at those
+    columns and 0 / NEG_INF elsewhere."""
+    tier = np.array([[0, -1, 1, -1, 2, 0], [-1, -1, -1, -1, -1, -1], [2, 2, -1, 1, -1, -1]],
+                    np.int32)
+    slot = np.arange(18, dtype=np.int32).reshape(3, 6) + 100
+    wslot, wtier, nwalk, pick = paged_attention.compact_walk(jnp.asarray(slot),
+                                                             jnp.asarray(tier), 4)
+    np.testing.assert_array_equal(np.asarray(nwalk), [4, 0, 3])
+    np.testing.assert_array_equal(np.asarray(wtier),
+                                  [[0, 1, 2, 0], [-1, -1, -1, -1], [2, 2, 1, -1]])
+    np.testing.assert_array_equal(np.asarray(wslot)[0], [100, 102, 104, 105])
+    np.testing.assert_array_equal(np.asarray(wslot)[2, :3], [112, 113, 115])
+    vals = jnp.asarray(np.arange(12, dtype=np.float32).reshape(3, 4) + 1)
+    mass, base = paged_attention.scatter_walk(pick, vals, -vals)
+    expect = np.zeros((3, 6), np.float32)
+    expect[0, [0, 2, 4, 5]] = [1, 2, 3, 4]
+    expect[2, [0, 1, 3]] = [9, 10, 11]
+    np.testing.assert_array_equal(np.asarray(mass), expect)
+    np.testing.assert_array_equal(np.asarray(base),
+                                  np.where(expect > 0, -expect, paged_attention.NEG_INF))
 
 
 def test_host_mass_matches_ref_oracle():
@@ -348,3 +455,49 @@ def test_engine_fused_telemetry_live_and_launches_counted():
     assert float(eng.cache.manager.telemetry.history.sum()) > 0.0
     assert stats.attn_launches == eng.la * stats.steps
     assert eng.cache.decode_steps_recorded == stats.steps
+
+def test_walk_slots_proxy_follows_the_mode():
+    """The fused path bills each row's held pages, one grid step each; the
+    per-pool oracle every column of every pool's table."""
+    held = np.array([0, 1, 5, 7])
+    assert ops.decode_walk_slots(held, 8, 2) == 13
+    ops.use_fused(False)
+    assert ops.decode_walk_slots(held, 8, 2) == 2 * 8 * 4
+
+
+def test_engine_walk_counters_and_page_bound():
+    """The engine bills walked page slots and attended pages from host-side
+    page counts, equal to what the device tables hold at each step; no
+    slot ever holds more pages (warm + cold + host) than one sequence's
+    max_pages, the walk bound the decode step passes."""
+    import jax
+
+    from repro.configs.base import ModelConfig, TierScapeRunConfig
+    from repro.models import Model
+    from repro.serving import TieredEngine
+
+    cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
+                      n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=128,
+                      head_dim=16)
+    model = Model(cfg)
+    eng = TieredEngine(model, model.init(jax.random.PRNGKey(1)), batch_slots=2,
+                       page_tokens=8, max_seq_len=96, recent_window=16,
+                       ts=TierScapeRunConfig(enabled=True, policy="analytical",
+                                             alpha=0.0, window_steps=4))
+    rng = np.random.default_rng(2)
+    for n in (40, 56):
+        eng.submit(rng.integers(1, cfg.vocab_size, n), max_new_tokens=24)
+    walked = pages = held_max = 0
+    while (any(s is not None for s in eng.slots) or eng.queue) and eng.stats.steps < 64:
+        eng._fill_slots()
+        st = eng.cache.state
+        held = np.asarray(st.warm_n) + np.asarray(st.cold_n) + np.asarray(st.host_n)
+        held_max = max(held_max, int(held.max()))
+        assert held_max <= eng.cache.max_pages
+        walked += ops.decode_walk_slots(held.ravel(), eng.cache.max_pages, 2)
+        pages += int(held.sum())
+        eng.step()
+    stats = eng.finish()
+    assert stats.completed == 2 and held_max > 0
+    assert (stats.attn_walk_slots, stats.attn_pages) == (walked, pages)
+    assert 0 < stats.attn_pages <= stats.attn_walk_slots

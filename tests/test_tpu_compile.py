@@ -1,4 +1,6 @@
-"""Served-path Pallas kernels compile for a TPU v5e at Qwen1.5-4B widths.
+"""Served-path Pallas kernels compile for a TPU v5e at served widths:
+Qwen1.5-4B's, and for the fused attention kernel also InternLM2-20B's at
+the benchmark cell's batch and table shapes.
 
 Nothing runs: each test lowers a kernel with ``interpret=False`` against a
 described (not attached) ``v5e:2x2`` topology and compiles it with the TPU
@@ -18,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import repro.configs as configs
+from repro.kernels import paged_attention
 from repro.kernels.dequant_page import dequant_pages
 from repro.kernels.paged_attention import fused_tiered_attention
 from repro.kernels.quant_page import quant_pages
@@ -86,16 +89,34 @@ def test_transcode_pages_compiles(one_chip, src, dst):
              _payload(src), SCALES)
 
 
-def test_fused_tiered_attention_compiles(one_chip):
+FUSED_SHAPES = {
+    # name: (batch, KV heads, query heads per KV head, head dim, table
+    # width, walk bound); 384 pages = max_seq_len 6144 in 16-token pages
+    "internlm2_20b_8l": (16, 8, 6, 128, 3 * 384, 384),
+    "qwen1_5_4b": (16, KV, H // KV, HD, 3 * 384, 384),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FUSED_SHAPES))
+def test_fused_tiered_attention_compiles(one_chip, name):
     """One launch over int8 + int4 class buffers, host sentinel rows and the
-    recent window; the unified table has warm + cold + host columns."""
-    fn = functools.partial(fused_tiered_attention, page_tokens=T, interpret=False)
+    recent window; the unified table has warm + cold + host columns and the
+    kernel walks at most one sequence's pages. The kernel states its VMEM
+    ceiling, v5e's default scoped VMEM, and the compiler refuses a kernel
+    whose blocks and scratch outgrow it: the compile is the VMEM check."""
+    assert paged_attention.VMEM_LIMIT_BYTES <= 16 << 20
+    b, kv, group, hd, ms, walk = FUSED_SHAPES[name]
+    h = kv * group
+    payload = {bits: ((PAGES, T, kv, hd if bits == 8 else hd // 2),
+                      jnp.int8 if bits == 8 else jnp.uint8) for bits in (8, 4)}
+    scales = ((PAGES, T, kv), jnp.float32)
+    fn = functools.partial(fused_tiered_attention, page_tokens=T, walk=walk, interpret=False)
     _compile(
         fn, one_chip,
-        ((B, H, HD), jnp.bfloat16),
-        _payload(8), SCALES, _payload(8), SCALES,
-        _payload(4), SCALES, _payload(4), SCALES,
-        ((PAGES, KV, HD), jnp.float32),
-        ((B, R, KV, HD), jnp.bfloat16), ((B, R, KV, HD), jnp.bfloat16),
-        ((B, 3 * MP), jnp.int32), ((B, 3 * MP), jnp.int32), ((B,), jnp.int32),
+        ((b, h, hd), jnp.bfloat16),
+        payload[8], scales, payload[8], scales,
+        payload[4], scales, payload[4], scales,
+        ((PAGES, kv, hd), jnp.float32),
+        ((b, R, kv, hd), jnp.bfloat16), ((b, R, kv, hd), jnp.bfloat16),
+        ((b, ms), jnp.int32), ((b, ms), jnp.int32), ((b,), jnp.int32),
     )
